@@ -47,7 +47,6 @@ def bench(n: int, m: int, k: int, iters: int, grids, inners, seed: int = 0):
     from jax.sharding import NamedSharding
 
     from repro.backend.sharded import make_sharded_als
-    from repro.compat import set_mesh
     from repro.core import init_u0
     from repro.core.topk import DistTopK
     from repro.data import synthetic_journal_corpus
@@ -99,7 +98,7 @@ def bench(n: int, m: int, k: int, iters: int, grids, inners, seed: int = 0):
                 # a real copy so the timing loop can repeat
                 return jax.device_put(jnp.array(u0, copy=True), u_sh)
 
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 t0 = time.perf_counter()
                 res = run(dist, u_fresh(), iters)
                 jax.block_until_ready(res.u)

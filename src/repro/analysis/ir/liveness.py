@@ -96,23 +96,29 @@ def intermediate_avals(jaxpr) -> Iterator[Tuple[object, object, int]]:
                 yield aval, eqn, depth
 
 
+def block_dims(bm) -> Tuple[int, ...]:
+    """Int block dims of one ``pallas_call`` BlockMapping.  Each entry is a
+    ``Blocked``/``Element`` dim carrying ``block_size`` or a ``Squeezed``
+    dim (no size, counts as 1).  A whole-array block (no ``grid``, no
+    BlockSpec) is a full-extent ``Blocked`` dim per axis."""
+    return tuple(int(getattr(d, "block_size", 1) or 1)
+                 for d in bm.block_shape)
+
+
 def _pallas_working_set(eqn) -> int:
     """Per-step VMEM block working set of a ``pallas_call`` eqn: one block
     per operand/output BlockSpec (the tile auditor separately checks the
-    double-buffered figure against the VMEM budget)."""
+    double-buffered figure against the VMEM budget).  Scalar-prefetch
+    operands live in SMEM and have no block mapping."""
     gm = eqn.params.get("grid_mapping")
     if gm is None:
         return 0
     total = 0
-    for bm in getattr(gm, "block_mappings", ()):  # inputs and outputs
-        shape_dtype = getattr(bm, "array_shape_dtype", None)
-        itemsize = (shape_dtype.dtype.itemsize
-                    if shape_dtype is not None else 4)
+    for bm in gm.block_mappings:  # inputs and outputs
         block = 1
-        for d in getattr(bm, "block_shape", ()):
-            if isinstance(d, int):
-                block *= d
-        total += block * itemsize
+        for d in block_dims(bm):
+            block *= d
+        total += block * bm.array_aval.dtype.itemsize
     return total
 
 

@@ -15,14 +15,16 @@ the kernels' docstrings make by hand today:
   slabs with k = 4).
 * **VMEM budget** — the double-buffered per-step working set (2x the sum
   of block bytes) must fit the ~16 MiB VMEM.  Where a target declares
-  ``documented_vmem_bytes`` (``bsr_spmm``'s 192 KiB docstring claim), the
+  ``documented_vmem_bytes`` (``bsr_spmm``'s 68 KiB docstring claim), the
   computed working set must match it — the comment becomes a checked fact.
 """
 from __future__ import annotations
 
 from repro.analysis.ir.framework import IRContext, IRPass, IRTarget, \
     register_ir_pass
-from repro.analysis.ir.liveness import _pallas_working_set, iter_eqns
+from repro.analysis.ir.liveness import (
+    _pallas_working_set, block_dims, iter_eqns,
+)
 
 #: per-core VMEM on current TPUs (v4/v5): ~16 MiB
 VMEM_BUDGET = 16 * 1024 * 1024
@@ -37,10 +39,9 @@ def _sublane(dtype) -> int:
     return {1: 32, 2: 16}.get(itemsize, 8)
 
 
-def _block_dims(bm):
-    """Int block dims of one BlockMapping (mapped/None dims count as 1)."""
-    return tuple(int(d) if isinstance(d, int) else 1
-                 for d in getattr(bm, "block_shape", ()))
+def _kernel_name(eqn) -> str:
+    """The kernel function's name, from the kernel jaxpr's debug info."""
+    return eqn.params["jaxpr"].debug_info.func_name
 
 
 @register_ir_pass
@@ -54,8 +55,7 @@ class PallasTilesPass(IRPass):
         for eqn, _depth in iter_eqns(target.jaxpr()):
             if eqn.primitive.name != "pallas_call":
                 continue
-            kname = eqn.params.get("name_and_src_info")
-            kname = getattr(kname, "name", None) or str(kname)
+            kname = _kernel_name(eqn)
             if kname in seen:  # same kernel traced at several call sites
                 continue
             seen.add(kname)
@@ -65,11 +65,9 @@ class PallasTilesPass(IRPass):
         gm = eqn.params.get("grid_mapping")
         if gm is None:
             return
-        for idx, bm in enumerate(getattr(gm, "block_mappings", ())):
-            sd = getattr(bm, "array_shape_dtype", None)
-            if sd is None:
-                continue
-            block = _block_dims(bm)
+        for idx, bm in enumerate(gm.block_mappings):
+            sd = bm.array_aval
+            block = block_dims(bm)
             shape = tuple(int(d) for d in sd.shape)
             if len(block) != len(shape):
                 continue  # mapped-dim mismatch; nothing checkable

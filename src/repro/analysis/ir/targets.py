@@ -320,9 +320,11 @@ def _kernel_targets() -> List[IRTarget]:
     out.append(IRTarget(
         name="kernel:bsr_spmm", kind="kernel", trace=trace_spmm,
         operand_bytes=_nbytes(bsr, u),
-        # the docstring's "(128,128,128) uses 192 KiB" claim, now checked:
-        # bm*bk tile + bk*kb U slab + bm*kb acc, f32
-        documented_vmem_bytes=3 * 128 * 128 * 4,
+        # the docstring's working-set claim, now checked: bm*bk tile +
+        # bk*kb U slab + bm*kb acc, f32, with kb = k for a factor no wider
+        # than the k tile ("(128, 128, k=4) uses 68 KiB")
+        documented_vmem_bytes=(
+            (c["bm"] * c["bk"] + c["bk"] * c["k"] + c["bm"] * c["k"]) * 4),
         budget_key="kernel:bsr_spmm"))
 
     def trace_spmm_gram():

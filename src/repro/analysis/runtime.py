@@ -26,11 +26,9 @@ the static ledger and what the allocator actually reserves::
     report = memory_guard(jitted_fn, *args, max_temp_bytes=1 << 30)
     print(report.temp_bytes, report.argument_bytes)
 
-On a jax without the monitoring hooks (or a backend whose executables
-expose no memory stats), both degrade explicitly: ``recompile_guard``
-raises unless ``allow_unsupported=True``; ``memory_guard`` likewise, and
-its degraded report has ``supported=False`` (callers should skip, not
-pass).
+On a backend whose executables expose no memory stats, ``memory_guard``
+raises unless ``allow_unsupported=True``, and its degraded report then has
+``supported=False`` (callers should skip, not pass).
 """
 from __future__ import annotations
 
@@ -56,28 +54,14 @@ class CompilationCounter:
 
     count: int = 0
     events: List[str] = dataclasses.field(default_factory=list)
-    supported: bool = True
 
     def _observe(self, event: str) -> None:
         self.count += 1
         self.events.append(event)
 
 
-def _monitoring():
-    try:
-        from jax._src import monitoring
-    except ImportError:
-        return None
-    if not (hasattr(monitoring, "register_event_duration_secs_listener")
-            and hasattr(monitoring,
-                        "_unregister_event_duration_listener_by_callback")):
-        return None
-    return monitoring
-
-
 @contextlib.contextmanager
-def recompile_guard(max_compiles: int = 0, *, allow_unsupported: bool = False
-                    ) -> Iterator[CompilationCounter]:
+def recompile_guard(max_compiles: int = 0) -> Iterator[CompilationCounter]:
     """Fail if the block triggers more than ``max_compiles`` XLA
     compilations.
 
@@ -86,16 +70,9 @@ def recompile_guard(max_compiles: int = 0, *, allow_unsupported: bool = False
     check runs at block exit; an exception already propagating wins over
     the guard's own error.
     """
-    monitoring = _monitoring()
-    counter = CompilationCounter(supported=monitoring is not None)
-    if monitoring is None:
-        if not allow_unsupported:
-            raise RuntimeError(
-                "recompile_guard needs jax._src.monitoring event-duration "
-                "listeners; pass allow_unsupported=True to degrade to a "
-                "no-op (and skip the assertion yourself)")
-        yield counter
-        return
+    from jax._src import monitoring
+
+    counter = CompilationCounter()
 
     def _listener(event: str, duration_secs: float, **kwargs) -> None:
         if event == COMPILE_EVENT:
@@ -105,7 +82,7 @@ def recompile_guard(max_compiles: int = 0, *, allow_unsupported: bool = False
     try:
         yield counter
     finally:
-        monitoring._unregister_event_duration_listener_by_callback(_listener)
+        monitoring.unregister_event_duration_listener(_listener)
     if counter.count > max_compiles:
         raise RecompilationError(
             f"{counter.count} XLA compilation(s) inside a "

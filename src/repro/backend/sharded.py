@@ -61,7 +61,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.backend.base import MatmulBackend, get_backend
-from repro.compat import SHARD_MAP_NO_CHECK, shard_map as _shard_map
 from repro.core import distributed as _dist
 from repro.core.distributed import DistBSR, DistCSR, make_dist_specs
 from repro.kernels.bsr import BSR, BSROperand
@@ -405,12 +404,12 @@ def _sharded_als_shard_fn(mesh, rows_axes, cols_axis, sparsify_u, sparsify_v,
                        sparsify_v=sparsify_v, track_error=track_error,
                        backend=be)
 
-    return _shard_map(
+    return jax.shard_map(
         step_fn,
         mesh=mesh,
         in_specs=(*fmt.leaf_specs(rows_axes, cols_axis), u_spec),
         out_specs=out_specs,
-        **SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
 
 
@@ -507,13 +506,13 @@ def _sharded_online_shard_fn(mesh, rows_axes, cols_axis, sparsify_u,
             local, u, OnlineStats(av=av, gv=gv), forget, iters=iters,
             sparsify_u=sparsify_u, sparsify_v=sparsify_v, backend=be)
 
-    return _shard_map(
+    return jax.shard_map(
         step_fn,
         mesh=mesh,
         in_specs=(*fmt.leaf_specs(rows_axes, cols_axis),
                   u_spec, u_spec, rep, rep),
         out_specs=out_specs,
-        **SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
 
 

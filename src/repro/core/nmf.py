@@ -118,7 +118,9 @@ def _bsr_relative_error(a: BSROperand, u: jax.Array, v: jax.Array,
     uf = u.astype(jnp.float32)
     vf = v.astype(jnp.float32)
     cross = bsr_dot_uv(a.bsr, u, v)
-    approx_sq = jnp.sum((uf.T @ uf) * (vf.T @ vf))
+    hi = jax.lax.Precision.HIGHEST
+    approx_sq = jnp.sum(jnp.dot(uf.T, uf, precision=hi)
+                        * jnp.dot(vf.T, vf, precision=hi))
     err_sq = jnp.maximum(a_sqnorm - 2.0 * cross + approx_sq, 0.0)
     return jnp.sqrt(err_sq) / jnp.sqrt(jnp.maximum(a_sqnorm, 1e-30))
 

@@ -112,15 +112,13 @@ def shape_bucket(n: int, m: Optional[int] = None,
 
 
 def device_kind() -> str:
-    """Normalized accelerator identity for the ledger key (e.g.
-    ``tpu_v5e``, ``cpu``)."""
+    """Normalized accelerator identity for the ledger key: the first
+    device's ``device_kind`` lower-cased with spaces joined by ``_`` (a
+    TPU v5e reports ``TPU v5 lite``, so its key is ``tpu_v5_lite``; the
+    CPU reports ``cpu``).  A failed probe raises."""
     import jax
 
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:  # repro: allow[exception-hygiene] device_kind is a best-effort ledger label; any probe failure (uninitialized backend, exotic plugin) falls back to the backend name, which is always available
-        kind = jax.default_backend()
-    return "_".join(str(kind).lower().split())
+    return "_".join(jax.devices()[0].device_kind.lower().split())
 
 
 def ledger_path() -> Path:
@@ -208,7 +206,8 @@ def legal_candidates(
 
     * minor block dims (bk for the tile, kb for the dense slab) must be
       128-lane multiples — full-extent exemptions are the *kernel's* doing
-      (it clamps kb to the padded k), so the pre-filter stays conservative;
+      (a factor no wider than kb is one full-width k block), so the
+      pre-filter stays conservative;
     * second-minor dims (bm, bk) must be sublane multiples for the dtype;
     * the double-buffered working set of both the separate kernel and the
       fused spmm+gram kernel must fit :data:`VMEM_BUDGET`.

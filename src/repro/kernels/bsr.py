@@ -206,7 +206,8 @@ def bsr_from_scipy(sp_matrix, bm: int = 128, bk: int = 128,
 
 def bsr_dot_uv(a: BSR, u: jax.Array, v: jax.Array) -> jax.Array:
     """``<A, U V^T>`` contracted tile-wise: sum over occupied tiles of
-    ``sum(tile * (U_blk V_blk^T))``, accumulated in f32.  Peak temporary is
+    ``sum(tile * (U_blk V_blk^T))``, contracted at full f32 precision
+    (``HIGHEST``), like the kernels' f32 dots.  Peak temporary is
     ~tile_volume * k / bk — a bk-fold saving over flattening the tiles to
     COO and gathering (tile_volume, k) slabs of U and V.  This is the
     cross term of the relative error for both the local BSR operand and a
@@ -223,7 +224,8 @@ def bsr_dot_uv(a: BSR, u: jax.Array, v: jax.Array) -> jax.Array:
     v_blk = v_blk[a.block_cols]  # (nrb, bcap, bk, k); padded slots see
     # block 0, harmless: their tiles are all-zero
     return jnp.einsum("isrc,ird,iscd->",
-                      a.tiles.astype(jnp.float32), u_blk, v_blk)
+                      a.tiles.astype(jnp.float32), u_blk, v_blk,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def bsr_to_coo(a: BSR):

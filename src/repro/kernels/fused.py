@@ -14,8 +14,7 @@ arXiv:1506.08938).
 Grid: (n_row_blocks, bcap), bcap innermost.  Unlike ``bsr_spmm`` there is
 no k tiling — the slab spans the full factor rank k (small by
 construction), which Mosaic handles as a single possibly-sub-lane block
-exactly like ``gram``'s (bm, k) slabs, and which skips the k -> kb=128
-zero-padding the separate kernel pays when k < 128.  VMEM working set per
+exactly like ``gram``'s (bm, k) slabs.  VMEM working set per
 step: bm*bk (tile) + bk*k (U slab) + bm*k (acc) operand-dtype elements
 plus the f32 k*k Gram accumulator — (128, 128, k=4) uses ~68 KiB, audited
 by the ``pallas-tiles`` IR pass against this docstring's
@@ -29,6 +28,13 @@ so block 0 is covered even in an all-padding operand); row-blocks no tile
 references are folded in afterwards by a masked correction term that
 ``lax.cond`` skips entirely when coverage is complete — the common case
 for real corpora, where every document block holds some term.
+
+SMEM: the two scalar-prefetched ``(nrb, bcap)`` tables (``block_cols`` and
+the flags) grow with the tile grid, so large grids launch once per
+row-block range from :func:`repro.kernels.bsr_spmm.row_block_chunks`; each
+launch returns its rows of the product and its part of the Gram, and the
+parts are summed.  The flags are computed over the whole grid first, so
+every distinct block still lands in exactly one part.
 """
 from __future__ import annotations
 
@@ -41,7 +47,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.bsr import BSR, BSROperand
-from repro.kernels.bsr_spmm import pad_rows
+from repro.kernels.bsr_spmm import mxu_precision, pad_rows, row_block_chunks
 
 
 def _spmm_gram_kernel(block_cols_ref, gram_flags_ref, tiles_ref, u_ref,
@@ -57,15 +63,18 @@ def _spmm_gram_kernel(block_cols_ref, gram_flags_ref, tiles_ref, u_ref,
     def _init_gram():
         gram_ref[...] = jnp.zeros_like(gram_ref)
 
+    tile = tiles_ref[0, 0]
     u = u_ref[...]  # (bk, k) slab, already in VMEM for the tile product
     out_ref[...] += jnp.dot(
-        tiles_ref[0, 0], u, preferred_element_type=out_ref.dtype
+        tile, u, precision=mxu_precision(tile, u),
+        preferred_element_type=out_ref.dtype,
     )
 
     @pl.when(gram_flags_ref[i, s] != 0)
     def _accumulate_gram():
         uf = u.astype(jnp.float32)
-        gram_ref[...] += jnp.dot(uf.T, uf, preferred_element_type=jnp.float32)
+        gram_ref[...] += jnp.dot(uf.T, uf, precision=jax.lax.Precision.HIGHEST,
+                                 preferred_element_type=jnp.float32)
 
 
 def _coverage(block_cols: jax.Array, ncb: int):
@@ -82,34 +91,21 @@ def _coverage(block_cols: jax.Array, ncb: int):
     return flags, first_pos < size
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def bsr_spmm_gram(
-    a: BSR, u: jax.Array, interpret: bool = False
-) -> Tuple[jax.Array, jax.Array]:
-    """``(dense(A) @ U, U^T U)`` in one Pallas launch.
-
-    The product matches :func:`repro.kernels.bsr_spmm.bsr_spmm` bit-for-bit
-    (same tile stream, same accumulation order); the Gram is accumulated in
-    f32 like :func:`repro.kernels.gram.gram` but in referenced-block order,
-    so it agrees to f32 roundoff, not bitwise.  Returns ``(y, gram)`` with
-    ``y`` cropped to (n, k) and ``gram`` (k, k) f32.
-    """
-    nrb, bcap, bm, bk = a.tiles.shape
-    n, _m = a.shape
-    k = u.shape[1]
-    u_p = pad_rows(u, bk)
-    ncb = u_p.shape[0] // bk
-    flags, covered = _coverage(a.block_cols, ncb)
-
-    grid = (nrb, bcap)
-    y, g = pl.pallas_call(
+def _spmm_gram_launch(block_cols, flags, tiles, u_p, r0: int,
+                      interpret: bool):
+    """One launch over row-blocks ``r0 .. r0 + len(block_cols)`` of the full
+    ``tiles`` array: those row-blocks' product rows and their Gram part."""
+    nr, bcap = block_cols.shape
+    _, _, bm, bk = tiles.shape
+    k = u_p.shape[1]
+    return pl.pallas_call(
         _spmm_gram_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(nr, bcap),
             in_specs=[
                 pl.BlockSpec((1, 1, bm, bk),
-                             lambda i, s, cols, flags: (i, s, 0, 0)),
+                             lambda i, s, cols, flags: (r0 + i, s, 0, 0)),
                 pl.BlockSpec((bk, k),
                              lambda i, s, cols, flags: (cols[i, s], 0)),
             ],
@@ -119,11 +115,39 @@ def bsr_spmm_gram(
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((nrb * bm, k), u.dtype),
+            jax.ShapeDtypeStruct((nr * bm, k), u_p.dtype),
             jax.ShapeDtypeStruct((k, k), jnp.float32),
         ],
         interpret=interpret,
-    )(a.block_cols, flags, a.tiles, u_p)
+    )(block_cols, flags, tiles, u_p)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def bsr_spmm_gram(
+    a: BSR, u: jax.Array, interpret: bool = False
+) -> Tuple[jax.Array, jax.Array]:
+    """``(dense(A) @ U, U^T U)`` in one sweep over the tiles: one Pallas
+    launch, or one per row-block range where the grid's SMEM tables need
+    splitting.
+
+    The product matches :func:`repro.kernels.bsr_spmm.bsr_spmm` bit-for-bit
+    (same tile stream, same accumulation order); the Gram is accumulated in
+    f32 like :func:`repro.kernels.gram.gram` but in referenced-block order,
+    so it agrees to f32 roundoff, not bitwise.  Returns ``(y, gram)`` with
+    ``y`` cropped to (n, k) and ``gram`` (k, k) f32.
+    """
+    nrb, bcap, _bm, bk = a.tiles.shape
+    n, _m = a.shape
+    u_p = pad_rows(u, bk)
+    ncb = u_p.shape[0] // bk
+    flags, covered = _coverage(a.block_cols, ncb)
+
+    ys, gs = zip(*[
+        _spmm_gram_launch(a.block_cols[r0:r1], flags[r0:r1], a.tiles, u_p,
+                          r0, interpret)
+        for r0, r1 in row_block_chunks(nrb, bcap, 2)])
+    y = jnp.concatenate(ys)
+    g = functools.reduce(jnp.add, gs)
 
     def _add_unreferenced(g):
         # fold in the row-blocks no occupied tile references: mask U down
@@ -131,7 +155,8 @@ def bsr_spmm_gram(
         # is incomplete (lax.cond), so fully-covered operands pay nothing.
         row_covered = covered[jnp.arange(u_p.shape[0]) // bk]
         um = jnp.where(row_covered[:, None], 0.0, u_p.astype(jnp.float32))
-        return g + jnp.dot(um.T, um, preferred_element_type=jnp.float32)
+        return g + jnp.dot(um.T, um, precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
 
     g = jax.lax.cond(jnp.all(covered), lambda g: g, _add_unreferenced, g)
     return y[:n], g

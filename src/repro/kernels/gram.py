@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.autotune import resolve_tiles
+from repro.kernels.bsr_spmm import mxu_precision
 
 
 def _gram_kernel(u_ref, out_ref):
@@ -26,7 +27,8 @@ def _gram_kernel(u_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     u = u_ref[...]
-    out_ref[...] += jnp.dot(u.T, u, preferred_element_type=out_ref.dtype)
+    out_ref[...] += jnp.dot(u.T, u, precision=mxu_precision(u, u),
+                            preferred_element_type=out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "interpret"))

@@ -126,8 +126,6 @@ def run_cell(cfg, shape, mesh, verbose=True, save_hlo: Optional[str] = None,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):  # older jax: [per-module dict]
-            ca = ca[0] if ca else {}
         ma = compiled.memory_analysis()
         rec.update(
             status="ok",

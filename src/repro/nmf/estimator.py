@@ -378,7 +378,6 @@ class EnforcedNMF:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from repro.backend.sharded import make_sharded_online
-        from repro.compat import set_mesh
         from repro.core.topk import DistTopK
         from repro.launch.mesh import make_nmf_mesh
         from repro.nmf.solvers import dist_budget, mesh_inner_backend
@@ -417,7 +416,7 @@ class EnforcedNMF:
             av=jax.device_put(stats.av, NamedSharding(mesh, u_spec)),
             gv=jax.device_put(stats.gv, NamedSharding(mesh, P())),
         )
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             res = engine(dist, u, stats, n_inner, forget)
         if mc_pad != mc:  # drop the empty padding documents' loadings
             res = res._replace(v=res.v[:mc])

@@ -224,72 +224,23 @@ def _run_chunked(run, config: NMFConfig, u0: jax.Array, solver_name: str,
     return FitResult.concatenate(parts, converged=converged)
 
 
-def _demote_operand(a: Matrix) -> Matrix:
-    """The jnp-csr view of a Pallas-path operand, for the kernel-failure
-    fallback: BSR tile grids unpack through the element COO (work
-    proportional to stored nonzeros, never a dense materialization);
-    everything else already is a csr-compatible operand."""
-    if isinstance(a, BSROperand):
-        from repro.kernels.bsr import bsr_to_coo
-        from repro.sparse.csr import from_coo
-
-        rows, cols, vals = bsr_to_coo(a.bsr)
-        return from_coo(rows, cols, vals, a.shape)
-    return a
-
-
-def _with_kernel_fallback(run, a: Matrix, config: NMFConfig, make_run):
-    """Graceful degradation for the Pallas path: if kernel dispatch fails
-    (hardware without the required MXU support, a lowering bug, an
-    injected ``"pallas-dispatch"`` fault), re-run the fit on the jnp-csr
-    reference backend with a single warning instead of killing it.  The
-    fallback is sticky for the rest of the fit; checkpoints stay valid
-    across it because the resume fingerprint deliberately ignores the
-    backend."""
-    state = {"fallback": None}
-
-    def guarded(u_init, iters):
-        if state["fallback"] is None:
-            try:
-                faults.fire("pallas-dispatch")
-                return run(u_init, iters)
-            except Exception as exc:  # noqa: BLE001 — any dispatch failure degrades
-                warnings.warn(
-                    f"pallas-bsr kernel dispatch failed ({exc!r}); falling "
-                    "back to the jnp-csr backend for this fit",
-                    RuntimeWarning)
-                state["fallback"] = make_run(_demote_operand(a), "jnp-csr")
-        return state["fallback"](u_init, iters)
-
-    return guarded
-
-
 def _als_family(a: Matrix, config: NMFConfig, u0: jax.Array,
                 solver_name: str) -> FitResult:
     from repro.backend import resolve_backend
 
     n, m = a.shape
+    # fuse the relu+threshold epilogue into one Pallas pass when the backend
+    # asks for it (the jnp backends keep the legacy two-pass epilogue so
+    # legacy results stay bit-for-bit)
+    fused = resolve_backend(a, config.backend).fuse_epilogue
+    sp_u = config.sparsity.sparsifier(n, config.k, "u", fused=fused)
+    sp_v = config.sparsity.sparsifier(m, config.k, "v", fused=fused)
 
-    def make_run(operand, backend):
-        # fuse the relu+threshold epilogue into one Pallas pass when the
-        # backend asks for it (the jnp backends keep the legacy two-pass
-        # epilogue so legacy results stay bit-for-bit) — resolved per
-        # operand/backend pair so the kernel-failure fallback rebuilds
-        # *unfused* sparsifiers along with the csr matmuls
-        fused = resolve_backend(operand, backend).fuse_epilogue
-        sp_u = config.sparsity.sparsifier(n, config.k, "u", fused=fused)
-        sp_v = config.sparsity.sparsifier(m, config.k, "v", fused=fused)
+    def run(u_init, iters):
+        return als_nmf(a, u_init, iters=iters, sparsify_u=sp_u,
+                       sparsify_v=sp_v, track_error=config.track_error,
+                       backend=config.backend)
 
-        def run(u_init, iters):
-            return als_nmf(operand, u_init, iters=iters, sparsify_u=sp_u,
-                           sparsify_v=sp_v, track_error=config.track_error,
-                           backend=backend)
-
-        return run
-
-    run = make_run(a, config.backend)
-    if resolve_backend(a, config.backend).name.startswith("pallas-bsr"):
-        run = _with_kernel_fallback(run, a, config, make_run)
     ckpt = FitCheckpointer.from_config(config, a)
     return _run_chunked(run, config, u0, solver_name, ckpt=ckpt)
 
@@ -643,7 +594,6 @@ def solve_distributed(a: Matrix, config: NMFConfig, u0: jax.Array) -> FitResult:
     from jax.sharding import NamedSharding
 
     from repro.backend.sharded import make_sharded_als
-    from repro.compat import set_mesh
     from repro.core.topk import DistTopK
     from repro.launch.mesh import make_nmf_mesh
 
@@ -677,7 +627,7 @@ def solve_distributed(a: Matrix, config: NMFConfig, u0: jax.Array) -> FitResult:
                               NamedSharding(mesh, u_spec))
 
     def run(u_init, iters):
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return engine(dist, u_init, iters)
 
     ckpt = FitCheckpointer.from_config(config, a)

@@ -21,9 +21,6 @@ Instrumented sites (each names the ``key`` it is consulted with):
 * ``"poison-step"`` — the solver drivers NaN-poison the factor entering
   iteration/chunk ``key``, so the in-engine health monitor must flag it
   and the driver must roll back.
-* ``"pallas-dispatch"`` — the ALS-family runners raise at kernel dispatch
-  (key ignored), so the pallas-bsr -> jnp-csr degradation path runs on
-  hardware where the kernel would otherwise succeed.
 * ``"prefetch-worker"`` — the prefetch worker thread exits *silently*
   before packing item ``key`` (no error, no done sentinel), so the
   consumer-side dead-worker watchdog must notice.

@@ -19,8 +19,8 @@ What the fingerprint pins vs. what it deliberately ignores:
 * **Ignored** — ``iters`` (resuming with a larger budget is the point),
   ``tol``, ``mesh_shape`` (snapshots are saved gathered and restored with
   ``device_put(x, sharding)`` against the *current* mesh, so a 2x2 fit may
-  resume on 4x1 — elastic restart), ``backend`` (the pallas->csr
-  degradation path must be able to resume a pallas run), prefetch knobs,
+  resume on 4x1 — elastic restart), ``backend`` (every backend computes
+  the same factorization, so a run may resume on another), prefetch knobs,
   and the checkpoint settings themselves.
 
 Array state rides in the store's npz payload; host-side scalars, histories

@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import SHARD_MAP_NO_CHECK, shard_map as _shard_map
 from repro.core.topk import topk_project_bisect
 
 Params = Any
@@ -71,9 +70,9 @@ def _compressed_shard_fn(loss_fn, mesh, data_axes, density,
         err_specs,
     )
     out_specs = (P(), replicated(params_def), err_specs)
-    return _shard_map(
+    return jax.shard_map(
         local_fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **SHARD_MAP_NO_CHECK,
+        check_vma=False,
     )
 
 

@@ -5,43 +5,6 @@ import pytest
 # their own XLA_FLAGS; nothing here may set device-count flags.
 jax.config.update("jax_enable_x64", False)
 
-# The LM-model / dry-run stack targets the modern jax API surface
-# (jax.set_mesh, jax.sharding.get_abstract_mesh, dict-valued
-# compiled.cost_analysis()).  On older jax these tests fail on API
-# availability, not repo logic — skip them so the suite stays a signal for
-# everything that can run here.  The NMF stack runs on both API generations
-# via repro.compat.
-_MODERN_JAX = hasattr(jax, "set_mesh") and hasattr(jax.sharding,
-                                                   "get_abstract_mesh")
-
-_MODERN_JAX_ONLY = {
-    "test_train_driver_resume",
-    "test_hlo_analysis_scales_loops",
-    "test_lower_compile_small_mesh",
-    "test_multipod_axes_small",
-    "test_model_attention_flash_path_matches",
-    "test_decode_matches_forward_dense",
-    "test_decode_step",
-    "test_microbatched_train_matches_shape",
-    "test_prefill_step",
-    "test_train_step",
-    "test_chunked_loss_grad_matches",
-    "test_moe_capacity_drops_tokens_gracefully",
-    "test_moe_dispatch_matches_dense_mixture",
-    "test_serving_engine_drains_all_requests",
-}
-
-
-def pytest_collection_modifyitems(config, items):
-    if _MODERN_JAX:
-        return
-    skip = pytest.mark.skip(
-        reason="LM model stack requires the modern jax API "
-               "(jax.set_mesh / jax.sharding.get_abstract_mesh)")
-    for item in items:
-        if item.name.split("[")[0] in _MODERN_JAX_ONLY:
-            item.add_marker(skip)
-
 
 @pytest.fixture(scope="session")
 def rng():

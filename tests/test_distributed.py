@@ -34,7 +34,6 @@ def test_dist_als_matches_single_device():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import set_mesh
         from repro.backend.sharded import make_sharded_als
         from repro.core.distributed import distribute_csr
         from repro.core.topk import DistTopK
@@ -46,7 +45,7 @@ def test_dist_als_matches_single_device():
         a = np.asarray(to_dense(a_sp))
         dist = distribute_csr(a, 4, 2)
         u0 = np.asarray(init_u0(jax.random.PRNGKey(2), 256, 5))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             run = make_sharded_als(mesh, ("data",), "model",
                                    sparsify_u=DistTopK(55, ("data",)),
                                    sparsify_v=DistTopK(300, ("model",)))
@@ -78,7 +77,6 @@ def test_dist_als_multipod_axes():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import set_mesh
         from repro.backend.sharded import make_sharded_als
         from repro.core.distributed import distribute_csr
         from repro.core.topk import DistTopK
@@ -90,7 +88,7 @@ def test_dist_als_multipod_axes():
         a = np.asarray(to_dense(a_sp))
         dist = distribute_csr(a, 4, 2)
         u0 = np.asarray(init_u0(jax.random.PRNGKey(2), 128, 4))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             run = make_sharded_als(mesh, ("pod", "data"), "model",
                                    sparsify_u=DistTopK(40, ("pod", "data")),
                                    sparsify_v=DistTopK(100, ("model",)))
@@ -111,7 +109,6 @@ def test_compressed_grads_error_feedback():
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.compat import set_mesh
         from repro.training.compression import make_compressed_grad_fn, init_error_state
         mesh = jax.make_mesh((4,), ("data",))
         def loss_fn(params, batch):
@@ -120,7 +117,7 @@ def test_compressed_grads_error_feedback():
         params = {"w": jnp.asarray(np.random.default_rng(0).standard_normal((8, 4)), jnp.float32)}
         batch = {"x": jnp.asarray(np.random.default_rng(1).standard_normal((16, 8)), jnp.float32),
                  "y": jnp.asarray(np.random.default_rng(2).standard_normal((16, 4)), jnp.float32)}
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             gf = make_compressed_grad_fn(loss_fn, mesh, ("data",), density=0.25)
             err = init_error_state(params, 4)
             loss, g, err2 = gf(params, batch, err)
@@ -141,7 +138,6 @@ def test_compressed_grads_error_feedback():
 def test_single_device_shard_map_paths():
     """The sharded engine code path also runs on a 1x1 mesh in-process."""
     from repro.backend.sharded import make_sharded_als
-    from repro.compat import set_mesh
     from repro.core import init_u0
     from repro.core.distributed import distribute_csr
     from repro.core.topk import DistTopK
@@ -152,7 +148,7 @@ def test_single_device_shard_map_paths():
     a = np.asarray(to_dense(a_sp))
     dist = distribute_csr(a, 1, 1)
     u0 = init_u0(jax.random.PRNGKey(0), 64, 4)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         run = make_sharded_als(mesh, ("data",), "model",
                                sparsify_u=DistTopK(30, ("data",)))
         res = run(dist, u0, 8)

@@ -30,7 +30,6 @@ def corpus():
 def test_positive_control_fresh_jit_is_counted():
     with recompile_guard(max_compiles=50) as counter:
         jax.jit(lambda x: x * 3.5)(jnp.ones(16)).block_until_ready()
-    assert counter.supported
     assert counter.count >= 1
 
 

@@ -93,6 +93,24 @@ def test_injected_exception_type_is_customizable():
             faults.maybe_kill("kill", 1)
 
 
+def test_pallas_kernel_failure_raises(monkeypatch):
+    """A kernel that fails on the pallas-bsr path fails the fit: nothing
+    re-runs it on another backend."""
+    from repro.backend.pallas_bsr import PallasBsrBackend
+
+    def refuse(self, a, v):
+        raise Boom("kernel refused")
+
+    monkeypatch.setattr(PallasBsrBackend, "matmul_with_gram", refuse)
+    a = np.abs(np.random.default_rng(3).normal(size=(23, 41))).astype(
+        np.float32)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        with pytest.raises(Boom, match="kernel refused"):
+            EnforcedNMF(NMFConfig(k=3, iters=4, seed=1,
+                                  backend="pallas-bsr")).fit(a)
+
+
 # ---------------------------------------------------------------------------
 # fingerprints: what a resume accepts and what it refuses
 # ---------------------------------------------------------------------------

@@ -152,14 +152,13 @@ def test_dist_topk_matches_exact_on_1x1_mesh():
     exact top-t whose size is within histogram-bin resolution of t."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import SHARD_MAP_NO_CHECK, shard_map
     from repro.core.topk import DistTopK, topk_project_exact
 
     x = jax.random.uniform(jax.random.PRNGKey(42), (64, 8))
     t = 100
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    fn = shard_map(DistTopK(t, ("data",)), mesh=mesh,
-                   in_specs=P(), out_specs=P(), **SHARD_MAP_NO_CHECK)
+    fn = jax.shard_map(DistTopK(t, ("data",)), mesh=mesh,
+                       in_specs=P(), out_specs=P(), check_vma=False)
     kept = fn(x)
     exact = topk_project_exact(x, t)
     kept_mask = np.asarray(kept != 0)

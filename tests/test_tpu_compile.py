@@ -1,0 +1,116 @@
+"""Compile the Pallas kernels of the main path for a described TPU v5e.
+
+Nothing runs: each kernel is lowered and compiled by the TPU compiler for a
+chip that is described, not attached, at the widths of the paper's three
+corpora (``configs/nmf_paper.py``) with a fully-filled tile grid and k=5.
+Mosaic refuses here what interpret mode accepts — unaligned blocks, more
+SMEM or VMEM than a core has — so these tests guard the chip path without
+a chip.
+
+The topology is described inside a module fixture (never at import): only
+one process at a time may load the TPU compiler library, and every test
+worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.configs.nmf_paper import NMF_CONFIGS
+from repro.kernels.bsr import BSR
+from repro.kernels.bsr_spmm import bsr_spmm, row_block_chunks
+from repro.kernels.fused import bsr_spmm_gram
+from repro.kernels.gram import gram
+from repro.kernels.project_mask import project_mask
+
+CORPORA = ("reuters", "pubmed", "wikipedia")
+BM = BK = 128
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means no description
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(shape, sharding, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _full_bsr(n, m, sharding):
+    """A fully-filled (n, m) tile grid: every row-block holds every
+    column-block, the largest operand the corpus shape can produce."""
+    nrb, ncb = -(-n // BM), -(-m // BK)
+    return BSR(_sds((nrb, ncb, BM, BK), sharding),
+               _sds((nrb, ncb), sharding, jnp.int32), (n, m))
+
+
+def _compiled_text(fn, *args):
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _launches(text):
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+@pytest.mark.parametrize("corpus", CORPORA)
+@pytest.mark.parametrize("transposed", [False, True],
+                         ids=["term_major", "doc_major"])
+def test_bsr_spmm_compiles(one_chip, corpus, transposed):
+    cfg = NMF_CONFIGS[corpus]
+    n, m, k = cfg["n_terms"], cfg["n_docs"], cfg["k"]
+    if transposed:
+        n, m = m, n
+    a = _full_bsr(n, m, one_chip)
+    text = _compiled_text(lambda a, u: bsr_spmm(a, u), a,
+                          _sds((m, k), one_chip))
+    assert _launches(text) == len(row_block_chunks(a.nrb, a.bcap, 1))
+
+
+@pytest.mark.parametrize("corpus", CORPORA)
+@pytest.mark.parametrize("transposed", [False, True],
+                         ids=["term_major", "doc_major"])
+def test_fused_spmm_gram_compiles(one_chip, corpus, transposed):
+    """Both orientations of the fused kernel fit SMEM at every width: the
+    Wikipedia term-major grid (1121 x 98 tiles) splits into row-block
+    launches."""
+    cfg = NMF_CONFIGS[corpus]
+    n, m, k = cfg["n_terms"], cfg["n_docs"], cfg["k"]
+    if transposed:
+        n, m = m, n
+    a = _full_bsr(n, m, one_chip)
+    text = _compiled_text(lambda a, u: bsr_spmm_gram(a, u), a,
+                          _sds((m, k), one_chip))
+    chunks = row_block_chunks(a.nrb, a.bcap, 2)
+    assert _launches(text) == len(chunks)
+    if corpus == "wikipedia" and not transposed:
+        assert len(chunks) > 1
+
+
+@pytest.mark.parametrize("corpus", CORPORA)
+@pytest.mark.parametrize("side", ["terms", "docs"])
+def test_gram_compiles(one_chip, corpus, side):
+    cfg = NMF_CONFIGS[corpus]
+    rows = cfg["n_terms"] if side == "terms" else cfg["n_docs"]
+    text = _compiled_text(lambda u: gram(u), _sds((rows, cfg["k"]), one_chip))
+    assert _launches(text) == 1
+
+
+@pytest.mark.parametrize("corpus", CORPORA)
+@pytest.mark.parametrize("side", ["terms", "docs"])
+def test_project_mask_compiles(one_chip, corpus, side):
+    cfg = NMF_CONFIGS[corpus]
+    rows = cfg["n_terms"] if side == "terms" else cfg["n_docs"]
+    text = _compiled_text(lambda x, tau: project_mask(x, tau),
+                          _sds((rows, cfg["k"]), one_chip),
+                          _sds((), one_chip))
+    assert _launches(text) == 1
