@@ -183,7 +183,8 @@ def als_nmf(
     be = _resolve(a, backend)
     n, k = u0.shape
     m = a.shape[1]
-    a_sqnorm = be.sqnorm(a)
+    with jax.named_scope("als.error"):
+        a_sqnorm = be.sqnorm(a)  # E's constant, once a fit
 
     def error_of(u, v):
         if not track_error:
@@ -195,34 +196,49 @@ def als_nmf(
         # each half-step's sparse product and Gram read the same factor, so
         # they come from one backend hook: fused into a single kernel sweep
         # on the Pallas path, separate matmul+gram calls (bit-for-bit the
-        # previous body) everywhere else
-        atu, gu = be.matmul_t_with_gram(a, u)
-        v = solve_gram(be.reduce_u(gu), atu)
-        v = _epilogue(v, sparsify_v)
+        # previous body) everywhere else.  The named scopes only label the
+        # ops (their ``op_name`` metadata) for a profiler trace.
+        with jax.named_scope("als.v"):
+            with jax.named_scope("product"):
+                atu, gu = be.matmul_t_with_gram(a, u)
+            with jax.named_scope("solve"):
+                v = solve_gram(be.reduce_u(gu), atu)
+            with jax.named_scope("topk"):
+                v = _epilogue(v, sparsify_v)
 
-        av, gv = be.matmul_with_gram(a, v)
-        u_new = solve_gram(be.reduce_v(gv), av)
-        u_new = _epilogue(u_new, sparsify_u)
+        with jax.named_scope("als.u"):
+            with jax.named_scope("product"):
+                av, gv = be.matmul_with_gram(a, v)
+            with jax.named_scope("solve"):
+                u_new = solve_gram(be.reduce_v(gv), av)
+            with jax.named_scope("topk"):
+                u_new = _epilogue(u_new, sparsify_u)
 
-        # relative residual R = ||U_i - U_{i-1}||_F / ||U_i||_F with the
-        # squared norms reduced over U's shard axes (identity locally)
-        num = be.reduce_u(jnp.sum(jnp.square(u_new - u)))
-        den = be.reduce_u(jnp.sum(jnp.square(u_new)))
-        r = jnp.sqrt(num) / jnp.maximum(jnp.sqrt(den), 1e-30)
-        e = error_of(u_new, v)
-        nu = be.reduce_u(jnp.sum(u_new != 0))
-        nv = be.reduce_v(jnp.sum(v != 0))
-        max_nnz = jnp.maximum(max_nnz, nu + nv)
+        with jax.named_scope("als.health"):
+            # relative residual R = ||U_i - U_{i-1}||_F / ||U_i||_F with
+            # the squared norms reduced over U's shard axes (identity
+            # locally)
+            num = be.reduce_u(jnp.sum(jnp.square(u_new - u)))
+            den = be.reduce_u(jnp.sum(jnp.square(u_new)))
+            r = jnp.sqrt(num) / jnp.maximum(jnp.sqrt(den), 1e-30)
+        with jax.named_scope("als.error"):
+            e = error_of(u_new, v)
+        with jax.named_scope("als.health"):
+            nu = be.reduce_u(jnp.sum(u_new != 0))
+            nv = be.reduce_v(jnp.sum(v != 0))
+            max_nnz = jnp.maximum(max_nnz, nu + nv)
 
-        # FitHealth monitor: record the first iteration whose factors went
-        # non-finite or whose residual exploded.  Counting non-finite
-        # entries (rather than jnp.all(isfinite)) keeps the check a plain
-        # sum, so it rides the existing psum reduction hooks on a mesh.
-        bad_u = be.reduce_u(jnp.sum(~jnp.isfinite(u_new)).astype(jnp.int32))
-        bad_v = be.reduce_v(jnp.sum(~jnp.isfinite(v)).astype(jnp.int32))
-        bad = ((bad_u + bad_v > 0) | ~jnp.isfinite(r)
-               | (r > _RESIDUAL_BLOWUP))
-        health = jnp.where((health < 0) & bad, it, health)
+            # FitHealth monitor: record the first iteration whose factors
+            # went non-finite or whose residual exploded.  Counting
+            # non-finite entries (rather than jnp.all(isfinite)) keeps the
+            # check a plain sum, so it rides the existing psum reduction
+            # hooks on a mesh.
+            bad_u = be.reduce_u(
+                jnp.sum(~jnp.isfinite(u_new)).astype(jnp.int32))
+            bad_v = be.reduce_v(jnp.sum(~jnp.isfinite(v)).astype(jnp.int32))
+            bad = ((bad_u + bad_v > 0) | ~jnp.isfinite(r)
+                   | (r > _RESIDUAL_BLOWUP))
+            health = jnp.where((health < 0) & bad, it, health)
         return (u_new, v, max_nnz, health, it + 1), (r, e, nu, nv)
 
     init_nnz = be.reduce_u(jnp.sum(u0 != 0))

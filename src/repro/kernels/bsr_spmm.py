@@ -131,6 +131,7 @@ def _spmm_launch(block_cols, tiles, u_p, kb_eff: int, r0: int,
         ),
         out_shape=jax.ShapeDtypeStruct((nr * bm, u_p.shape[1]), u_p.dtype),
         interpret=interpret,
+        name="bsr_spmm",
     )(block_cols, tiles, u_p)
 
 

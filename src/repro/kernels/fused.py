@@ -119,6 +119,7 @@ def _spmm_gram_launch(block_cols, flags, tiles, u_p, r0: int,
             jax.ShapeDtypeStruct((k, k), jnp.float32),
         ],
         interpret=interpret,
+        name="bsr_spmm_gram",
     )(block_cols, flags, tiles, u_p)
 
 

@@ -43,6 +43,7 @@ def _gram_impl(u: jax.Array, bm: int, interpret: bool) -> jax.Array:
         out_specs=pl.BlockSpec((k, k), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((k, k), jnp.float32),
         interpret=interpret,
+        name="gram",
     )(u_p)
     return out
 

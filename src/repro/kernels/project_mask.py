@@ -43,6 +43,7 @@ def _project_mask_impl(
         ),
         out_shape=jax.ShapeDtypeStruct(x_p.shape, x.dtype),
         interpret=interpret,
+        name="project_mask",
     )(jnp.reshape(tau.astype(x.dtype), (1,)), x_p)
     return out[:n, :k]
 

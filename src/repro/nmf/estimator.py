@@ -174,51 +174,62 @@ class EnforcedNMF:
         ``resume`` overrides ``config.resume`` for this call: with a
         ``config.checkpoint_dir`` holding a snapshot of this same run, the
         fit continues from it instead of starting over (see
-        :mod:`repro.robustness`)."""
+        :mod:`repro.robustness`).
+
+        Under a profiler trace the call is the host span ``nmf.fit``;
+        inside it ``nmf.prepare`` (input coercion and the initial guess),
+        the solver's spans and ``nmf.seed_stats`` (the statistics
+        ``partial_fit`` continues from)."""
         from repro.data.corpus import as_chunk_source, is_corpus_input
 
-        cfg = self.config
-        if resume is not None:
-            cfg = cfg.replace(resume=bool(resume))
-        streamed = is_corpus_input(a)
-        if streamed:
-            if cfg.solver != "streaming":
-                raise ValueError(
-                    f"out-of-core corpora stream chunk-wise; the "
-                    f"{cfg.solver!r} solver needs a resident matrix — use "
-                    "solver='streaming' (or load the corpus yourself)")
-            a = as_chunk_source(a, chunk_docs=cfg.chunk_docs)
-        else:
-            a = self._coerce(a, chunkable=cfg.solver == "streaming",
-                             for_mesh=cfg.solver == "distributed")
-        n, m = a.shape
-        entry = get_solver(cfg.solver)
-        if u0 is None:
-            u0 = init_u0(jax.random.PRNGKey(cfg.seed), n,
-                         entry.u0_cols(cfg)).astype(cfg.jnp_dtype)
-        result = entry.fn(a, cfg, u0)
-        self.u_, self.v_, self.result_ = result.u, result.v, result
-        self.n_iter_ = result.n_iter
-        self.n_features_ = n
-        self.n_docs_seen_ = m  # fit is from-scratch; only partial_fit accumulates
-        self._m_ref = m
-        # seed streaming statistics so partial_fit continues from this fit;
-        # one extra backend spmm (~1/(2*iters) of the fit) beats pinning
-        # the corpus
-        if streamed:
-            stats = self._seed_stats_streamed(a)
-        else:
-            seed_backend = cfg.backend
-            if (seed_backend is not None
-                    and not get_backend(seed_backend).accepts(a)):
-                # the corpus stayed in a sliceable / shardable form
-                # (streaming fit keeps SpCSR for column chunks; the mesh
-                # paths re-pack per device) — seed through the operand's
-                # own backend instead
-                seed_backend = None
-            stats = seed_online_stats(a, self.v_, backend=seed_backend)
-        self._av_acc, self._gv_acc = stats.av, stats.gv
-        return self
+        with jax.profiler.TraceAnnotation("nmf.fit"):
+            cfg = self.config
+            if resume is not None:
+                cfg = cfg.replace(resume=bool(resume))
+            with jax.profiler.TraceAnnotation("nmf.prepare"):
+                streamed = is_corpus_input(a)
+                if streamed:
+                    if cfg.solver != "streaming":
+                        raise ValueError(
+                            f"out-of-core corpora stream chunk-wise; the "
+                            f"{cfg.solver!r} solver needs a resident "
+                            "matrix — use solver='streaming' (or load the "
+                            "corpus yourself)")
+                    a = as_chunk_source(a, chunk_docs=cfg.chunk_docs)
+                else:
+                    a = self._coerce(a, chunkable=cfg.solver == "streaming",
+                                     for_mesh=cfg.solver == "distributed")
+                n, m = a.shape
+                entry = get_solver(cfg.solver)
+                if u0 is None:
+                    u0 = init_u0(jax.random.PRNGKey(cfg.seed), n,
+                                 entry.u0_cols(cfg)).astype(cfg.jnp_dtype)
+            result = entry.fn(a, cfg, u0)
+            self.u_, self.v_, self.result_ = result.u, result.v, result
+            self.n_iter_ = result.n_iter
+            self.n_features_ = n
+            # fit is from-scratch; only partial_fit accumulates
+            self.n_docs_seen_ = m
+            self._m_ref = m
+            # seed streaming statistics so partial_fit continues from this
+            # fit; one extra backend spmm (~1/(2*iters) of the fit) beats
+            # pinning the corpus
+            with jax.profiler.TraceAnnotation("nmf.seed_stats"):
+                if streamed:
+                    stats = self._seed_stats_streamed(a)
+                else:
+                    seed_backend = cfg.backend
+                    if (seed_backend is not None
+                            and not get_backend(seed_backend).accepts(a)):
+                        # the corpus stayed in a sliceable / shardable form
+                        # (streaming fit keeps SpCSR for column chunks; the
+                        # mesh paths re-pack per device) — seed through the
+                        # operand's own backend instead
+                        seed_backend = None
+                    stats = seed_online_stats(a, self.v_,
+                                              backend=seed_backend)
+            self._av_acc, self._gv_acc = stats.av, stats.gv
+            return self
 
     def _seed_stats_streamed(self, source) -> OnlineStats:
         """Full-corpus online statistics ``(A V, V^T V)`` from a chunk

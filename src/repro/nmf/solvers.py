@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.nmf import (
-    Matrix, _matmul_t, _relative_error, als_nmf, solve_gram,
+    Matrix, NMFResult, _matmul_t, _relative_error, als_nmf, solve_gram,
 )
 from repro.core.sequential import SequentialResult, sequential_als_nmf
 from repro.kernels.bsr import BSROperand
@@ -106,18 +106,17 @@ def _history_meta(parts) -> dict:
     }
 
 
-def _part_from_saved(hist: dict, solver_name: str) -> FitResult:
-    """Rebuild the pre-crash history as a synthetic first ``FitResult``
-    part.  Its factors are ``None`` — only the *last* part's factors are
-    ever read by :meth:`FitResult.concatenate`, matching how the tol-chunk
-    loop already treats intermediate parts (their ``u`` buffers are
-    donated into the next chunk)."""
-    residual = jnp.asarray(hist["residual"], jnp.float32)
-    return FitResult(
-        u=None, v=None, residual=residual,
+def _part_from_saved(hist: dict) -> NMFResult:
+    """Rebuild the pre-crash history as a synthetic first part.  Its
+    factors are ``None`` — only the *last* part's factors are ever read by
+    :meth:`FitResult.concatenate`, matching how the tol-chunk loop already
+    treats intermediate parts (their ``u`` buffers are donated into the
+    next chunk)."""
+    return NMFResult(
+        u=None, v=None,
+        residual=jnp.asarray(hist["residual"], jnp.float32),
         error=jnp.asarray(hist["error"], jnp.float32),
         max_nnz=jnp.int32(hist["max_nnz"]),
-        solver=solver_name, n_iter=int(residual.shape[0]),
         nnz_u=jnp.asarray(hist["nnz_u"], jnp.int32),
         nnz_v=jnp.asarray(hist["nnz_v"], jnp.int32),
     )
@@ -160,6 +159,11 @@ def _run_chunked(run, config: NMFConfig, u0: jax.Array, solver_name: str,
     * ``u0_src`` — a never-donated reference to the initial guess, the
       rollback target when no checkpoint exists yet (the mesh engine
       donates the ``u0`` actually passed to ``run``).
+
+    Host spans name the driver's steps in a profiler trace: ``nmf.dispatch``
+    around each ``run`` (which returns once the engine is enqueued),
+    ``nmf.sync`` around each blocking read of a device value, and
+    ``nmf.result`` around assembling the :class:`FitResult`.
     """
     place = jnp.asarray if place is None else place
     u0_src = u0 if u0_src is None else u0_src
@@ -177,7 +181,7 @@ def _run_chunked(run, config: NMFConfig, u0: jax.Array, solver_name: str,
                     f"iterations but config.iters is {total}; raise iters "
                     "(the fingerprint ignores it) to continue the run")
             u = place(arrays["u"])
-            parts = [_part_from_saved(meta["history"], solver_name)]
+            parts = [_part_from_saved(meta["history"])]
             mark = (done, 1)
 
     if config.tol > 0.0:
@@ -189,9 +193,15 @@ def _run_chunked(run, config: NMFConfig, u0: jax.Array, solver_name: str,
     rollbacks = 0
     while done < total:
         step = min(step_base, total - done)
-        res = run(faults.poison("poison-step", done, u), step)
-        if config.on_unhealthy != "ignore" and int(res.health) >= 0:
-            bad_at = done + int(res.health)
+        u_in = faults.poison("poison-step", done, u)
+        with jax.profiler.TraceAnnotation("nmf.dispatch"):
+            res = run(u_in, step)
+        first_bad = -1
+        if config.on_unhealthy != "ignore":
+            with jax.profiler.TraceAnnotation("nmf.sync"):
+                first_bad = int(res.health)
+        if first_bad >= 0:
+            bad_at = done + first_bad
             if (config.on_unhealthy == "raise"
                     or rollbacks >= config.max_rollbacks):
                 raise FitHealthError(
@@ -205,7 +215,8 @@ def _run_chunked(run, config: NMFConfig, u0: jax.Array, solver_name: str,
             if ckpt is not None and ckpt.last is not None:
                 host_u = ckpt.last[1]["u"]
             else:
-                host_u = jax.device_get(u0_src)
+                with jax.profiler.TraceAnnotation("nmf.sync"):
+                    host_u = jax.device_get(u0_src)
             u = place(_reseed_perturb(host_u, config.seed, rollbacks))
             warnings.warn(
                 f"{solver_name} fit went unhealthy at iteration {bad_at}; "
@@ -213,15 +224,21 @@ def _run_chunked(run, config: NMFConfig, u0: jax.Array, solver_name: str,
                 f"(attempt {rollbacks}/{config.max_rollbacks})",
                 RuntimeWarning)
             continue
-        parts.append(FitResult.from_nmf_result(res, solver_name))
+        parts.append(res)
         u, done = res.u, done + step
         if ckpt is not None and ckpt.due(done, total):
             ckpt.save(done, {"u": u}, history=_history_meta(parts))
             mark = (done, len(parts))
-        if config.tol > 0.0 and float(res.residual[-1]) <= config.tol:
-            converged = True
-            break
-    return FitResult.concatenate(parts, converged=converged)
+        if config.tol > 0.0:
+            with jax.profiler.TraceAnnotation("nmf.sync"):
+                last_r = float(res.residual[-1])
+            if last_r <= config.tol:
+                converged = True
+                break
+    with jax.profiler.TraceAnnotation("nmf.result"):
+        return FitResult.concatenate(
+            [FitResult.from_nmf_result(p, solver_name) for p in parts],
+            converged=converged)
 
 
 def _als_family(a: Matrix, config: NMFConfig, u0: jax.Array,

@@ -12,6 +12,7 @@ one process at a time may load the TPU compiler library, and every test
 worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,8 @@ from repro.kernels.bsr_spmm import bsr_spmm, row_block_chunks
 from repro.kernels.fused import bsr_spmm_gram
 from repro.kernels.gram import gram
 from repro.kernels.project_mask import project_mask
+
+from _hlo import SCOPE, loop_body, op_name
 
 CORPORA = ("reuters", "pubmed", "wikipedia")
 BM = BK = 128
@@ -114,3 +117,31 @@ def test_project_mask_compiles(one_chip, corpus, side):
                           _sds((rows, cfg["k"]), one_chip),
                           _sds((), one_chip))
     assert _launches(text) == 1
+
+
+def test_every_op_of_the_fit_loop_carries_a_scope(one_chip, monkeypatch):
+    """The whole enforced fit at Reuters width: on the chip every fusion,
+    kernel launch and nested loop of the ALS loop body sits in one of the
+    engine's named scopes, which a profiler trace reports per op."""
+    import repro.kernels.ops as ops
+    from repro.core.nmf import als_nmf
+    from repro.kernels.bsr import BSROperand
+    from repro.nmf.config import Sparsity
+
+    monkeypatch.setattr(ops, "_default_interpret", lambda: False)
+    cfg = NMF_CONFIGS["reuters"]
+    n, m, k = cfg["n_terms"], cfg["n_docs"], cfg["k"]
+    a = BSROperand(_full_bsr(n, m, one_chip), _full_bsr(m, n, one_chip),
+                   (n, m))
+    sparsity = Sparsity(t_u=55, mode="global")
+    text = _compiled_text(
+        lambda a, u0: als_nmf(
+            a, u0, iters=75, backend="pallas-bsr",
+            sparsify_u=sparsity.sparsifier(n, k, "u", fused=True)),
+        a, _sds((n, k), one_chip))
+    ops_of_body = [line for line in loop_body(text)
+                   if re.search(r"\s(fusion|custom-call|while)\(", line)]
+    assert sum('tpu_custom_call' in line for line in ops_of_body) == 3
+    unscoped = [line.strip()[:160] for line in ops_of_body
+                if not SCOPE.search(op_name(line))]
+    assert not unscoped, unscoped
