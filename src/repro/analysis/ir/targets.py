@@ -335,11 +335,12 @@ def _kernel_targets() -> List[IRTarget]:
     out.append(IRTarget(
         name="kernel:bsr_spmm_gram", kind="kernel", trace=trace_spmm_gram,
         operand_bytes=_nbytes(bsr, u),
-        # the fused.py docstring's working-set claim, now checked: bm*bk
-        # tile + bk*k U slab + bm*k acc (f32) plus the f32 k*k Gram
+        # the fused.py docstring's working-set claim, now checked: S*bm*bk
+        # tiles + the resident k*m factor + bm*k acc (f32) plus the f32 k*k
+        # Gram, with S = bcap here (3 slots, below the 16-tile step target)
         documented_vmem_bytes=(
-            (c["bm"] * c["bk"] + c["bk"] * c["k"] + c["bm"] * c["k"]) * 4
-            + c["k"] * c["k"] * 4),
+            (c["bcap"] * c["bm"] * c["bk"] + c["k"] * c["m"]
+             + c["bm"] * c["k"]) * 4 + c["k"] * c["k"] * 4),
         budget_key="kernel:bsr_spmm_gram"))
 
     ug = _sds((c["n"], c["k"]))

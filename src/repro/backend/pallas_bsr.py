@@ -5,9 +5,9 @@ the two BSR orientations built once at ingest (HBM traffic proportional to
 occupied blocks — the paper's memory/compute win restated for the MXU);
 ``gram`` streams (bm, k) row slabs through VMEM once.  The half-step pair
 hooks ``matmul_with_gram`` / ``matmul_t_with_gram`` run the *fused*
-spmm+gram kernel (:mod:`repro.kernels.fused`) — one grid sweep computes
-the sparse product and the Gram while the dense operand slab is resident
-in VMEM, halving the half-step's HBM reads of the factor.  Tile sizes
+spmm+gram kernel (:mod:`repro.kernels.fused`) — one launch computes
+the sparse product and the Gram from one copy of the dense factor held in
+VMEM, so the half-step reads the factor from HBM once.  Tile sizes
 resolve through the autotune ledger
 (:func:`repro.kernels.autotune.resolve_tiles`) unless pinned at
 construction.
@@ -30,9 +30,7 @@ import dataclasses
 import jax
 
 from repro.backend.base import LocalExecution, register_backend
-from repro.kernels.autotune import (
-    VMEM_BUDGET, fused_working_set, resolve_tiles,
-)
+from repro.kernels.autotune import fused_slots, resolve_tiles
 from repro.kernels.bsr import BSROperand, bsr_operand
 from repro.kernels.ops import gram_matrix, spmm, spmm_gram, spmm_t, spmm_t_gram
 from repro.sparse.csr import SpCSR, to_scipy
@@ -97,13 +95,14 @@ class PallasBsrBackend(LocalExecution):
     # -- fused half-step pair -------------------------------------------------
 
     def _fusable(self, bsr, x: jax.Array) -> bool:
-        """The fused kernel streams full-k slabs, so its working set grows
-        with k: fall back to the separate launches when the double-buffered
-        set would blow the VMEM budget (or fusion is disabled)."""
+        """The fused kernel holds the whole (k, m) factor in VMEM beside its
+        tiles, so its working set grows with k x m: fall back to the
+        separate launches where not even one tile fits beside the factor
+        (or fusion is disabled)."""
         if not self.fuse_halfstep:
             return False
-        ws = fused_working_set(bsr.bm, bsr.bk, x.shape[1], x.dtype.itemsize)
-        return 2 * ws <= VMEM_BUDGET
+        return fused_slots(bsr.bm, bsr.bk, x.shape[1], bsr.shape[1],
+                           bsr.bcap, x.dtype.itemsize) > 0
 
     def matmul_with_gram(self, a: BSROperand, v: jax.Array):
         if not self._fusable(a.bsr, v):

@@ -54,7 +54,7 @@ __all__ = [
     "TileConfig", "DEFAULT_TILES", "VMEM_BUDGET",
     "shape_bucket", "device_kind", "ledger_path", "load_ledger",
     "resolve_tiles", "legal_candidates", "spmm_working_set",
-    "fused_working_set", "autotune", "update_ledger",
+    "fused_working_set", "fused_slots", "autotune", "update_ledger",
 ]
 
 #: per-core VMEM budget the legality pre-filter enforces — keep in sync
@@ -186,11 +186,47 @@ def spmm_working_set(bm: int, bk: int, kb: int, itemsize: int = 4) -> int:
     return (bm * bk + bk * kb + bm * kb) * itemsize
 
 
-def fused_working_set(bm: int, bk: int, k: int, itemsize: int = 4) -> int:
-    """Per-step VMEM bytes of the fused spmm+gram kernel: (bm, bk) tile +
-    (bk, k) dense slab + (bm, k) accumulator in the operand dtype, plus the
-    f32 (k, k) gram accumulator."""
-    return (bm * bk + bk * k + bm * k) * itemsize + k * k * 4
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+#: bytes of tiles a fused grid step aims to hold: a step has a fixed cost
+#: of about 0.35 us on a TPU v5e, so one 64 KiB tile a step (0.08 us of
+#: HBM time) leaves most of the bandwidth idle, and ~1 MiB hides it
+FUSED_STEP_BYTES = 1 << 20
+
+
+def fused_working_set(bm: int, bk: int, k: int, m: int, slots: int = 1,
+                      itemsize: int = 4) -> int:
+    """VMEM bytes of the fused spmm+gram kernel over an operand with ``m``
+    columns, as Mosaic lays them out: the whole (k, m) factor resident once
+    (lane-dense, rows padded to the sublane tile, columns to whole
+    ``bk`` blocks and 128 lanes), ``slots`` (bm, bk) tiles and the (bm, k)
+    output block double-buffered (the block lane-padded to 128), and the
+    f32 (k, k) Gram."""
+    lanes = _round_up(_round_up(m, bk), 128)
+    factor = _round_up(k, _sublane(itemsize)) * lanes * itemsize
+    tiles = 2 * slots * bm * bk * itemsize
+    out = 2 * bm * _round_up(k, 128) * itemsize
+    gram = 2 * _round_up(k, 8) * _round_up(k, 128) * 4
+    return factor + tiles + out + gram
+
+
+def fused_slots(bm: int, bk: int, k: int, m: int, bcap: int,
+                itemsize: int = 4) -> int:
+    """Tiles the fused kernel takes a grid step: about
+    :data:`FUSED_STEP_BYTES` of them, no more than ``bcap``, spread evenly
+    over the steps a row-block needs, and fewer where the resident factor
+    leaves too little of :data:`VMEM_BUDGET`.  0 when not even one tile
+    fits beside the factor: the fused kernel cannot run."""
+    bcap = max(bcap, 1)
+    slots = min(bcap, max(1, FUSED_STEP_BYTES // (bm * bk * itemsize)))
+    while slots and fused_working_set(bm, bk, k, m, slots,
+                                      itemsize) > VMEM_BUDGET:
+        slots //= 2
+    if slots:
+        slots = -(-bcap // -(-bcap // slots))  # even out the last step
+    return slots
 
 
 #: default sweep grid — every value is a 128-lane multiple so the minor-dim
@@ -230,7 +266,7 @@ def legal_candidates(
             continue  # block larger than the (padded) operand is all padding
         if 2 * spmm_working_set(bm, bk, kb, itemsize) > VMEM_BUDGET:
             continue
-        if 2 * fused_working_set(bm, bk, k, itemsize) > VMEM_BUDGET:
+        if fused_working_set(bm, bk, k, m, 1, itemsize) > VMEM_BUDGET:
             continue
         out.append((bm, bk, kb))
     return out

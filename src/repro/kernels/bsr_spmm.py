@@ -24,8 +24,8 @@ fused spmm+gram variant of this kernel lives in
 
 SMEM: scalar prefetch copies the whole ``(nrb, bcap)`` ``block_cols``
 table into the core's 1 MiB SMEM, so one launch over a large tile grid
-does not fit (Wikipedia's term-major grid, 1121 x 98, needs 1.10 MiB for
-the fused kernel's two tables).  :func:`row_block_chunks` splits the grid
+does not fit (Wikipedia's term-major grid, 1121 x 98, needs 0.55 MiB for
+its table).  :func:`row_block_chunks` splits the grid
 into row-block ranges whose tables fit :data:`SMEM_PREFETCH_BUDGET`; each
 range is one launch over the full tile array (the tile index map adds the
 range's offset, so no tile is copied).  Output rows depend only on their
@@ -77,8 +77,8 @@ def pad_operand(u: jax.Array, bk: int, kb: int):
     """The shared pad step of the separate spmm kernels: rows up to a bk
     multiple and the effective k block ``kb_eff``.  A factor no wider than
     ``kb`` is one full-width k block with no column padding — the same
-    (bk, k) slab the fused kernel streams, so both kernels run identical
-    tile products; wider factors pad their columns up to a kb multiple."""
+    (bk, k) slab the fused kernel slices from its resident factor, so both
+    kernels run identical tile products; wider factors pad their columns up to a kb multiple."""
     u_p = pad_rows(u, bk)
     k = u.shape[1]
     if k <= kb:

@@ -98,13 +98,17 @@ def test_legal_candidates_default_grid_all_legal():
     for bm, bk, kb in cands:
         assert bk % 128 == 0 and kb % 128 == 0
         assert 2 * spmm_working_set(bm, bk, kb) <= VMEM_BUDGET
-        assert 2 * fused_working_set(bm, bk, 8) <= VMEM_BUDGET
+        assert fused_working_set(bm, bk, 8, 2048) <= VMEM_BUDGET
 
 
 def test_working_set_formulas():
     assert spmm_working_set(128, 128, 128) == 3 * 128 * 128 * 4
-    assert fused_working_set(128, 128, 4) == (
-        (128 * 128 + 128 * 4 + 128 * 4) * 4 + 4 * 4 * 4)
+    # the resident (k, m) factor once, rows to 8 sublanes and columns to
+    # whole bk blocks; S tiles and the lane-padded (bm, k) output block
+    # double-buffered; the (k, k) f32 Gram padded to an (8, 128) tile
+    assert fused_working_set(128, 128, 4, 300, slots=16) == (
+        8 * 384 * 4 + 2 * 16 * 128 * 128 * 4 + 2 * 128 * 128 * 4
+        + 2 * 8 * 128 * 4)
 
 
 def test_autotune_off_tpu_returns_default_fallback():

@@ -109,7 +109,8 @@ def test_fused_spmm_gram_t_orientation():
 
 def test_fused_spmm_gram_unreferenced_blocks():
     """Column blocks no occupied tile references must still contribute to
-    the Gram (the masked-correction path behind lax.cond)."""
+    the Gram: it is taken over the whole resident factor, not over the
+    slabs the tiles reference."""
     from repro.kernels.fused import bsr_spmm_gram
     rng = np.random.default_rng(4)
     a = np.zeros((128, 256), np.float32)
@@ -125,8 +126,7 @@ def test_fused_spmm_gram_unreferenced_blocks():
 
 def test_fused_spmm_gram_all_zero_operand():
     """Degenerate all-padding operand: product is zero, Gram is still the
-    full U^T U (block 0 is covered by padding slots; the correction folds
-    in the rest)."""
+    full U^T U of the resident factor."""
     from repro.kernels.fused import bsr_spmm_gram
     rng = np.random.default_rng(5)
     a = np.zeros((100, 180), np.float32)
@@ -136,6 +136,59 @@ def test_fused_spmm_gram_all_zero_operand():
     np.testing.assert_array_equal(np.asarray(y_f), np.zeros((100, 4)))
     np.testing.assert_allclose(np.asarray(g_f), np.asarray(u.T @ u),
                                rtol=1e-5, atol=1e-4)
+
+
+def _check_fused_against_separate(bsr, u):
+    """Fused product bitwise equal to bsr_spmm, Gram to f32 roundoff of
+    U^T U."""
+    from repro.kernels.fused import bsr_spmm_gram
+    y_sep = bsr_spmm(bsr, u, interpret=True)
+    y_f, g_f = bsr_spmm_gram(bsr, u, interpret=True)
+    np.testing.assert_array_equal(np.asarray(y_f), np.asarray(y_sep))
+    np.testing.assert_allclose(np.asarray(g_f), np.asarray(u.T @ u),
+                               rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("bcap,slots", [(59, 15), (3, 3)],
+                         ids=["prime_bcap", "bcap_below_step_target"])
+def test_fused_spmm_gram_slots_per_step(bcap, slots):
+    """128 x 128 f32 tiles aim at 16 a grid step.  PubMed's prime bcap of
+    59 takes 4 steps of 15 slots, the last running one slot past bcap,
+    which the kernel skips; a bcap below the target is one step of bcap
+    slots."""
+    from repro.kernels.autotune import FUSED_STEP_BYTES, fused_slots
+    rng = np.random.default_rng(bcap)
+    n, m, k = 200, bcap * 128, 5
+    a = _rand_sparse(rng, n, m)
+    bsr = bsr_from_dense(a, bm=128, bk=128)
+    assert bsr.bcap == bcap
+    assert fused_slots(128, 128, k, m, bcap) == slots
+    if bcap % slots:
+        assert bcap > slots  # some step runs past bcap
+    else:
+        assert slots == bcap < FUSED_STEP_BYTES // (128 * 128 * 4)
+    u = jnp.asarray(rng.standard_normal((m, k)).astype(np.float32))
+    _check_fused_against_separate(bsr, u)
+
+
+def test_fused_spmm_gram_row_block_launches(monkeypatch):
+    """A grid whose block_cols table overflows the SMEM budget runs as
+    several row-block launches; their rows concatenate to the same
+    product, and the Gram comes from the first launch alone."""
+    from repro.kernels import bsr_spmm as bsr_spmm_mod
+    from repro.kernels.fused import bsr_spmm_gram
+    monkeypatch.setattr(bsr_spmm_mod, "SMEM_PREFETCH_BUDGET", 1)
+    rng = np.random.default_rng(12)
+    n, m, k = 20 * 64, 7 * 64, 5
+    a = _rand_sparse(rng, n, m)
+    bsr = bsr_from_dense(a, bm=64, bk=64)
+    chunks = bsr_spmm_mod.row_block_chunks(bsr.nrb, bsr.bcap, 1)
+    assert chunks == [(0, 8), (8, 16), (16, 20)]
+    u = jnp.asarray(rng.standard_normal((m, k)).astype(np.float32))
+    jaxpr = jax.make_jaxpr(
+        lambda b, x: bsr_spmm_gram(b, x, interpret=True))(bsr, u)
+    assert str(jaxpr).count("pallas_call") == len(chunks)
+    _check_fused_against_separate(bsr, u)
 
 
 def test_fused_spmm_gram_bf16():

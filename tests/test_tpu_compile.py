@@ -83,9 +83,10 @@ def test_bsr_spmm_compiles(one_chip, corpus, transposed):
 @pytest.mark.parametrize("transposed", [False, True],
                          ids=["term_major", "doc_major"])
 def test_fused_spmm_gram_compiles(one_chip, corpus, transposed):
-    """Both orientations of the fused kernel fit SMEM at every width: the
-    Wikipedia term-major grid (1121 x 98 tiles) splits into row-block
-    launches."""
+    """Both orientations of the fused kernel fit SMEM and VMEM at every
+    width: one scalar-prefetched table a launch, so the Wikipedia term-major
+    grid (1121 x 98 tiles) splits into row-block launches, and the resident
+    factor fits beside the tiles at the widest factor (143,462 terms)."""
     cfg = NMF_CONFIGS[corpus]
     n, m, k = cfg["n_terms"], cfg["n_docs"], cfg["k"]
     if transposed:
@@ -93,10 +94,31 @@ def test_fused_spmm_gram_compiles(one_chip, corpus, transposed):
     a = _full_bsr(n, m, one_chip)
     text = _compiled_text(lambda a, u: bsr_spmm_gram(a, u), a,
                           _sds((m, k), one_chip))
-    chunks = row_block_chunks(a.nrb, a.bcap, 2)
+    chunks = row_block_chunks(a.nrb, a.bcap, 1)
     assert _launches(text) == len(chunks)
     if corpus == "wikipedia" and not transposed:
         assert len(chunks) > 1
+
+
+@pytest.mark.parametrize("corpus", CORPORA)
+@pytest.mark.parametrize("transposed", [False, True],
+                         ids=["term_major", "doc_major"])
+def test_paper_widths_take_the_fused_path(corpus, transposed):
+    """At the paper's k=5 the pallas-bsr backend runs both half-steps of
+    every corpus through the fused kernel, not the bsr_spmm + gram
+    fallback."""
+    from repro.backend.pallas_bsr import PallasBsrBackend
+
+    cfg = NMF_CONFIGS[corpus]
+    n, m, k = cfg["n_terms"], cfg["n_docs"], cfg["k"]
+    if transposed:
+        n, m = m, n
+    a = BSR(jax.ShapeDtypeStruct((-(-n // BM), -(-m // BK), BM, BK),
+                                 jnp.float32),
+            jax.ShapeDtypeStruct((-(-n // BM), -(-m // BK)), jnp.int32),
+            (n, m))
+    assert PallasBsrBackend()._fusable(a, jax.ShapeDtypeStruct((m, k),
+                                                               jnp.float32))
 
 
 @pytest.mark.parametrize("corpus", CORPORA)
