@@ -36,7 +36,7 @@ from repro.sparse.csr import SpCSR
 Sparsifier = Callable[[jax.Array], jax.Array]
 Matrix = Union[jax.Array, SpCSR, BSROperand]
 
-__all__ = ["NMFResult", "init_u0", "als_nmf", "solve_gram"]
+__all__ = ["NMFResult", "init_u0", "als_nmf", "factor_gram", "solve_gram"]
 
 
 class NMFResult(NamedTuple):
@@ -66,6 +66,13 @@ def init_u0(key: jax.Array, n: int, k: int, nnz: Optional[int] = None) -> jax.Ar
 
         u0 = topk_project_exact(u0, nnz)
     return u0
+
+
+def factor_gram(x: jax.Array) -> jax.Array:
+    """``x^T x`` contracted at full float32 precision (``HIGHEST``), like
+    the kernels' Grams: XLA's default precision on a TPU is one bfloat16
+    pass, whose rounding the solve against this Gram then carries."""
+    return jnp.dot(x.T, x, precision=jax.lax.Precision.HIGHEST)
 
 
 def solve_gram(gram: jax.Array, rhs: jax.Array, ridge: float = 1e-8) -> jax.Array:

@@ -20,8 +20,8 @@ inner passes over the chunk:
 Every product and every reduction goes through the pluggable
 :class:`~repro.backend.base.MatmulBackend` protocol, exactly like the batch
 engine: with a local backend (``jnp-dense`` / ``jnp-csr`` / ``pallas-bsr``)
-the ``reduce_*`` hooks are identity and the step is bit-for-bit the legacy
-single-device ``partial_fit`` loop; with a
+the ``reduce_*`` hooks are identity and the step is the legacy
+single-device ``partial_fit`` loop, to float32 rounding; with a
 :class:`repro.backend.sharded.ShardedBackend` (inside a shard_map — see
 :func:`repro.backend.sharded.make_sharded_online`) the chunk's columns are
 sharded over the mesh's ``cols`` axis, the statistics reductions become
@@ -97,7 +97,14 @@ def online_als_step(
     ``backend`` follows the batch-engine convention: a registry name, a
     ``MatmulBackend`` instance (how the sharded execution layer injects its
     mesh collectives), or ``None`` for operand-type auto-selection — which
-    reproduces the legacy estimator loop bit-for-bit on one device.
+    reproduces the legacy estimator loop on one device (to float32
+    rounding: XLA fuses the compiled loop's arithmetic, which the eager
+    loop ran op by op).
+
+    The loop body names its work for a profiler trace, as the batch engine
+    does: ``online.v/product``, ``online.v/solve``, ``online.v/topk``, the
+    same three under ``online.u`` (its ``solve`` adds the chunk to the
+    statistics), and ``online.health``.
     """
     be = _resolve(a_chunk, backend)
     k = u.shape[1]
@@ -108,22 +115,34 @@ def online_als_step(
         u, _v, _gv, _av, health, it = carry
         # fused half-step pairs, like the batch engine: one kernel sweep
         # computes the chunk product and the Gram on the Pallas path
-        atu, gu = be.matmul_t_with_gram(a_chunk, u)
-        v = solve_gram(be.reduce_u(gu), atu)
-        v = _epilogue(v, sparsify_v)
-        av_c, gv_c = be.matmul_with_gram(a_chunk, v)
-        gv = forget * stats.gv + be.reduce_v(gv_c)
-        av = forget * stats.av + av_c
-        u_new = solve_gram(gv, av)
-        u_new = _epilogue(u_new, sparsify_u)
+        with jax.named_scope("online.v"):
+            with jax.named_scope("product"):
+                atu, gu = be.matmul_t_with_gram(a_chunk, u)
+            with jax.named_scope("solve"):
+                v = solve_gram(be.reduce_u(gu), atu)
+            with jax.named_scope("topk"):
+                v = _epilogue(v, sparsify_v)
+        with jax.named_scope("online.u"):
+            with jax.named_scope("product"):
+                av_c, gv_c = be.matmul_with_gram(a_chunk, v)
+            # the normal equations of the whole stream: the pre-chunk
+            # statistics plus this chunk's
+            with jax.named_scope("solve"):
+                gv = forget * stats.gv + be.reduce_v(gv_c)
+                av = forget * stats.av + av_c
+                u_new = solve_gram(gv, av)
+            with jax.named_scope("topk"):
+                u_new = _epilogue(u_new, sparsify_u)
 
         # FitHealth monitor (mirrors the batch engine): plain sums over the
         # factors plus the replicated gv accumulator, phrased through the
         # reduce hooks so the same check psums on a mesh.
-        bad_u = be.reduce_u(jnp.sum(~jnp.isfinite(u_new)).astype(jnp.int32))
-        bad_v = be.reduce_v(jnp.sum(~jnp.isfinite(v)).astype(jnp.int32))
-        bad = (bad_u + bad_v > 0) | ~jnp.isfinite(jnp.sum(gv))
-        health = jnp.where((health < 0) & bad, it, health)
+        with jax.named_scope("online.health"):
+            bad_u = be.reduce_u(
+                jnp.sum(~jnp.isfinite(u_new)).astype(jnp.int32))
+            bad_v = be.reduce_v(jnp.sum(~jnp.isfinite(v)).astype(jnp.int32))
+            bad = (bad_u + bad_v > 0) | ~jnp.isfinite(jnp.sum(gv))
+            health = jnp.where((health < 0) & bad, it, health)
         return (u_new, v, gv, av, health, it + 1), None
 
     v0 = jnp.zeros((m_chunk, k), dtype=u.dtype)

@@ -47,6 +47,7 @@ import zlib
 from pathlib import Path
 from typing import Callable, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.robustness import faults
@@ -70,6 +71,12 @@ _META = "meta.json"
 #: instead of a hard failure (the stream then fits on the surviving
 #: chunks — degraded results, but a live run)
 SKIP_BAD_CHUNKS_ENV = "REPRO_STREAM_SKIP_BAD_CHUNKS"
+
+#: host spans of the :class:`Prefetcher` in a profiler trace: each call of
+#: the packer (on the worker thread, or inline with prefetch off), and the
+#: consumer blocked on the worker's queue
+PACK_SPAN = "nmf.stream.pack"
+STALL_SPAN = "nmf.stream.stall"
 
 
 class CorpusIntegrityError(RuntimeError):
@@ -386,6 +393,9 @@ class Prefetcher:
     degraded results.  A worker that dies without reporting (the moral
     equivalent of a segfault) is caught by a liveness watchdog on the
     consumer side rather than hanging the fit.
+
+    Under a profiler trace each pack is the host span :data:`PACK_SPAN` and
+    each wait of the consumer on the queue :data:`STALL_SPAN`.
     """
 
     _DONE = object()
@@ -427,7 +437,8 @@ class Prefetcher:
         while True:
             t0 = time.perf_counter()
             try:
-                packed = self._pack(item)
+                with jax.profiler.TraceAnnotation(PACK_SPAN):
+                    packed = self._pack(item)
             except OSError as exc:
                 self.stats["pack_s"] += time.perf_counter() - t0
                 if attempt < self._retries:
@@ -504,17 +515,18 @@ class Prefetcher:
             self.stats["max_queued"] = max(self.stats["max_queued"],
                                            self._q.qsize())
             t0 = time.perf_counter()
-            while True:
-                try:
-                    packed, exc = self._q.get(timeout=1.0)
-                    break
-                except queue.Empty:
-                    if not self._thread.is_alive():
-                        self._stop.set()
-                        raise RuntimeError(
-                            "prefetch worker died without reporting a "
-                            "result or an error; the stream cannot "
-                            "continue") from None
+            with jax.profiler.TraceAnnotation(STALL_SPAN):
+                while True:
+                    try:
+                        packed, exc = self._q.get(timeout=1.0)
+                        break
+                    except queue.Empty:
+                        if not self._thread.is_alive():
+                            self._stop.set()
+                            raise RuntimeError(
+                                "prefetch worker died without reporting a "
+                                "result or an error; the stream cannot "
+                                "continue") from None
             self.stats["stall_s"] += time.perf_counter() - t0
             if exc is not None:
                 self._stop.set()  # the raise abandons the stream mid-flight

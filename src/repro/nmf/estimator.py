@@ -33,7 +33,8 @@ import numpy as np
 from repro.backend import BSROperand, default_backend_name, get_backend
 from repro.core.distributed import DistBSR, DistCSR
 from repro.core.nmf import (
-    Matrix, _matmul, _matmul_t, _relative_error, init_u0, solve_gram,
+    Matrix, _matmul, _matmul_t, _relative_error, factor_gram, init_u0,
+    solve_gram,
 )
 from repro.core.online import (
     OnlineStats, init_online_stats, online_als_step, seed_online_stats,
@@ -179,7 +180,12 @@ class EnforcedNMF:
         Under a profiler trace the call is the host span ``nmf.fit``;
         inside it ``nmf.prepare`` (input coercion and the initial guess),
         the solver's spans and ``nmf.seed_stats`` (the statistics
-        ``partial_fit`` continues from)."""
+        ``partial_fit`` continues from).  A streamed fit adds, on this
+        thread, ``nmf.stream.chunk`` (each chunk step of the stream pass),
+        ``nmf.stream.stall`` (waiting on the prefetcher),
+        ``nmf.stream.ingest`` (a chunk's conversion to the backend operand
+        here) and ``nmf.stream.fold_in`` (the frozen-U pass), and on the
+        prefetch worker ``nmf.stream.pack`` around each pack."""
         from repro.data.corpus import as_chunk_source, is_corpus_input
 
         with jax.profiler.TraceAnnotation("nmf.fit"):
@@ -238,9 +244,12 @@ class EnforcedNMF:
         v = self.v_
         av = None
         for i, (lo, hi) in enumerate(source.schedule):
-            part = _matmul(self._coerce(source.load(i)), v[lo:hi])
+            host = source.load(i)
+            with jax.profiler.TraceAnnotation("nmf.stream.ingest"):
+                chunk = self._coerce(host)
+            part = _matmul(chunk, v[lo:hi])
             av = part if av is None else av + part
-        return OnlineStats(av=av, gv=v.T @ v)
+        return OnlineStats(av=av, gv=factor_gram(v))
 
     def fit_transform(self, a: ArrayLike,
                       u0: Optional[jax.Array] = None) -> jax.Array:
@@ -264,7 +273,7 @@ class EnforcedNMF:
         a_new = self._coerce(a_new)
         self._check_features(a_new)
         u = self.u_
-        v = solve_gram(u.T @ u, _matmul_t(a_new, u))
+        v = solve_gram(factor_gram(u), _matmul_t(a_new, u))
         return self._enforce_v(jnp.maximum(v, 0.0))
 
     def _v_sparsity(self, m_new: int) -> Sparsity:
@@ -310,7 +319,9 @@ class EnforcedNMF:
         :class:`~repro.data.corpus.PackedChunk` (mesh streaming only) or an
         already-distributed ``DistCSR`` / ``DistBSR`` shard grid skips the
         pad + distribute — the corpus prefetcher packs chunks ahead of
-        time, so the step consumes committed per-device buffers.
+        time, so the step consumes committed per-device buffers.  Any
+        other chunk converts to the backend's operand under the host span
+        ``nmf.stream.ingest``.
         """
         from repro.data.corpus import PackedChunk
 
@@ -331,7 +342,9 @@ class EnforcedNMF:
                     "distributed shard grids need solver='streaming' with "
                     "a non-1x1 mesh_shape")
         else:
-            a_chunk = self._coerce(a_chunk, for_mesh=self._mesh_streaming())
+            with jax.profiler.TraceAnnotation("nmf.stream.ingest"):
+                a_chunk = self._coerce(a_chunk,
+                                       for_mesh=self._mesh_streaming())
         self._check_features(a_chunk)
         n = a_chunk.shape[0]
         mc = mc_true if mc_true is not None else a_chunk.shape[1]

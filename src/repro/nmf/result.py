@@ -43,6 +43,11 @@ class FitResult:
     nnz_u: Optional[jax.Array] = None  # (n_iter,) where the solver tracks it
     nnz_v: Optional[jax.Array] = None
     error_granularity: str = "iteration"   # "iteration" | "block" | "chunk"
+    #: the streaming solver's prefetch counters, summed over its prefetched
+    #: passes (the stream and the fold-in): ``packed`` chunks, ``pack_s``
+    #: seconds inside the packer, ``stall_s`` seconds the fitting thread
+    #: waited for a chunk; ``None`` for the other solvers
+    stream_stats: Optional[dict] = None
 
     @property
     def final_error(self) -> float:

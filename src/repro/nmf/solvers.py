@@ -21,7 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.nmf import (
-    Matrix, NMFResult, _matmul_t, _relative_error, als_nmf, solve_gram,
+    Matrix, NMFResult, _matmul_t, _relative_error, als_nmf, factor_gram,
+    solve_gram,
 )
 from repro.core.sequential import SequentialResult, sequential_als_nmf
 from repro.kernels.bsr import BSROperand
@@ -361,37 +362,56 @@ def _make_packer(model):
     """The host-side pack function the stream (and its
     :class:`~repro.data.corpus.Prefetcher` worker) runs per chunk.
 
-    Local runs ``device_put`` the chunk's arrays, so the host→device copy
-    of chunk N+1 rides under chunk N's compute (the jitted step then finds
-    committed device buffers — same values it would have transferred
-    itself).  Mesh runs do the full ahead-of-time pack: pad to the grid +
-    per-device shard distribute (:meth:`EnforcedNMF._pack_mesh_chunk`),
-    returning a :class:`~repro.data.corpus.PackedChunk`."""
+    Local runs ingest the host chunk for the configured backend and
+    ``device_put`` the result, so the conversion and the host→device copy
+    of chunk N+1 ride under chunk N's compute, as the fold-in's do, and
+    ``partial_fit`` finds the backend's operand already on the device.  A
+    padded-CSR chunk never goes to the device on the ``pallas-bsr`` path,
+    so nothing there depends on its slot capacity (the fullest row, which
+    varies with the data and which the device pads to a multiple of 128).
+    Mesh runs do the full ahead-of-time pack: pad to the grid + per-device
+    shard distribute (:meth:`EnforcedNMF._pack_mesh_chunk`), returning a
+    :class:`~repro.data.corpus.PackedChunk`."""
     if model._mesh_streaming():
         return model._pack_mesh_chunk
-    return jax.device_put
+    return lambda chunk: jax.device_put(model._coerce(chunk))
 
 
-def _fold_in_streamed(model, source, config: NMFConfig) -> jax.Array:
+#: the :class:`~repro.data.corpus.Prefetcher` counters a streamed fit sums
+#: over its prefetched passes into ``FitResult.stream_stats``
+STREAM_STATS = ("packed", "pack_s", "stall_s")
+
+
+def _add_stream_stats(total: dict, stream) -> None:
+    for key in STREAM_STATS:
+        total[key] += stream.stats[key]
+
+
+def _fold_in_streamed(model, source, config: NMFConfig,
+                      stream_stats: dict) -> jax.Array:
     """Frozen-U fold-in of the whole corpus, one chunk at a time: each
     chunk contributes its rows of the (m, k) right-hand side ``A^T U``,
     then one shared Gram solve + relu + enforcement — the same normal
     equations :meth:`EnforcedNMF.transform` solves, without ever holding a
     resident corpus operand.  Runs the full schedule even when ``tol``
-    early-stopped the factor stream, so ``v`` always covers the corpus."""
-    u = model.u_
-    gram = u.T @ u
+    early-stopped the factor stream, so ``v`` always covers the corpus.
+    The prefetcher's counters add to ``stream_stats``; the pass is the
+    host span ``nmf.stream.fold_in``."""
     from repro.data.corpus import Prefetcher
 
-    parts = []
-    with Prefetcher(range(len(source.schedule)),
-                    lambda i: model._coerce(source.load(i)),
-                    depth=config.prefetch_depth,
-                    enabled=config.prefetch) as stream:
-        for chunk in stream:
-            parts.append(_matmul_t(chunk, u))
-    v = solve_gram(gram, jnp.concatenate(parts, axis=0))
-    return model._enforce_v(jnp.maximum(v, 0.0))
+    with jax.profiler.TraceAnnotation("nmf.stream.fold_in"):
+        u = model.u_
+        gram = factor_gram(u)
+        parts = []
+        with Prefetcher(range(len(source.schedule)),
+                        lambda i: model._coerce(source.load(i)),
+                        depth=config.prefetch_depth,
+                        enabled=config.prefetch) as stream:
+            for chunk in stream:
+                parts.append(_matmul_t(chunk, u))
+        _add_stream_stats(stream_stats, stream)
+        v = solve_gram(gram, jnp.concatenate(parts, axis=0))
+        return model._enforce_v(jnp.maximum(v, 0.0))
 
 
 def _restore_stream_state(model, ckpt, u0, config: NMFConfig, attempt: int):
@@ -449,7 +469,9 @@ def solve_streaming(a: Matrix, config: NMFConfig, u0: jax.Array) -> FitResult:
     ``residual`` is the cross-chunk U movement, ``error`` the relative
     reconstruction error of each chunk, and the final ``v`` is one frozen-U
     fold-in pass over the whole corpus (shape (m, k)), streamed chunk-wise
-    over the full schedule.
+    over the full schedule.  ``stream_stats`` sums the prefetcher's
+    counters over both passes.  Each chunk step of the stream is the host
+    span ``nmf.stream.chunk``.
     """
     from repro.data.corpus import PackedChunk, Prefetcher, as_chunk_source
     from repro.nmf.estimator import EnforcedNMF
@@ -503,6 +525,7 @@ def solve_streaming(a: Matrix, config: NMFConfig, u0: jax.Array) -> FitResult:
 
     rollbacks = 0
     replay = True
+    stream_stats = dict.fromkeys(STREAM_STATS, 0)
     while replay:
         replay = False
         with Prefetcher(range(start, n_chunks),
@@ -510,67 +533,69 @@ def solve_streaming(a: Matrix, config: NMFConfig, u0: jax.Array) -> FitResult:
                         depth=config.prefetch_depth,
                         enabled=config.prefetch) as stream:
             for idx, packed in zip(range(start, n_chunks), stream):
-                chunk = (packed.host if isinstance(packed, PackedChunk)
-                         else packed)
-                u_prev = model.u_
-                model.u_ = faults.poison("poison-step", idx, model.u_)
-                model.partial_fit(packed)
-                u, v = model.u_, model.v_
-                num = jnp.linalg.norm(u - u_prev)
-                den = jnp.maximum(jnp.linalg.norm(u), 1e-30)
-                r = num / den
-                residuals.append(r)
-                errors.append(_relative_error(chunk, u, v)
-                              if config.track_error else jnp.float32(0.0))
-                nu = jnp.sum(u != 0).astype(jnp.int32)
-                nv = jnp.sum(v != 0).astype(jnp.int32)
-                nnz_us.append(nu)
-                nnz_vs.append(nv)
-                max_nnz = jnp.maximum(max_nnz, nu + nv)
-                done = idx + 1
-                boundary = ckpt is not None and ckpt.due(done, n_chunks)
-                if ((boundary or done == n_chunks)
-                        and config.on_unhealthy != "ignore"
-                        and int(model.health_) >= 0):
-                    if (config.on_unhealthy == "raise"
-                            or rollbacks >= config.max_rollbacks):
-                        raise FitHealthError(
-                            f"streaming fit went unhealthy by chunk {idx}"
-                            + ("" if config.on_unhealthy == "raise" else
-                               f"; gave up after {rollbacks} rollback(s)"))
-                    rollbacks += 1
-                    start, keep = mark
-                    del residuals[keep:], errors[keep:]
-                    del nnz_us[keep:], nnz_vs[keep:]
-                    max_nnz = _restore_stream_state(
-                        model, ckpt, u0, config, rollbacks)
-                    warnings.warn(
-                        f"streaming fit went unhealthy by chunk {idx}; "
-                        f"rolling back to chunk {start} with reseeded RNG "
-                        f"(attempt {rollbacks}/{config.max_rollbacks})",
-                        RuntimeWarning)
-                    replay = True
-                    break
-                if boundary:
-                    ckpt.save(
-                        done,
-                        {"u": model.u_, "av": model._av_acc,
-                         "gv": model._gv_acc},
-                        history={
-                            "residual": [float(x) for x in residuals],
-                            "error": [float(x) for x in errors],
-                            "nnz_u": [int(x) for x in nnz_us],
-                            "nnz_v": [int(x) for x in nnz_vs],
-                            "max_nnz": int(max_nnz),
-                        },
-                        n_docs_seen=int(model.n_docs_seen_))
-                    mark = (done, len(residuals))
-                if config.tol > 0.0 and float(r) <= config.tol:
-                    converged = True
-                    break
+                with jax.profiler.TraceAnnotation("nmf.stream.chunk"):
+                    chunk = (packed.host if isinstance(packed, PackedChunk)
+                             else packed)
+                    u_prev = model.u_
+                    model.u_ = faults.poison("poison-step", idx, model.u_)
+                    model.partial_fit(packed)
+                    u, v = model.u_, model.v_
+                    num = jnp.linalg.norm(u - u_prev)
+                    den = jnp.maximum(jnp.linalg.norm(u), 1e-30)
+                    r = num / den
+                    residuals.append(r)
+                    errors.append(_relative_error(chunk, u, v)
+                                  if config.track_error else jnp.float32(0.0))
+                    nu = jnp.sum(u != 0).astype(jnp.int32)
+                    nv = jnp.sum(v != 0).astype(jnp.int32)
+                    nnz_us.append(nu)
+                    nnz_vs.append(nv)
+                    max_nnz = jnp.maximum(max_nnz, nu + nv)
+                    done = idx + 1
+                    boundary = ckpt is not None and ckpt.due(done, n_chunks)
+                    if ((boundary or done == n_chunks)
+                            and config.on_unhealthy != "ignore"
+                            and int(model.health_) >= 0):
+                        if (config.on_unhealthy == "raise"
+                                or rollbacks >= config.max_rollbacks):
+                            raise FitHealthError(
+                                f"streaming fit went unhealthy by chunk {idx}"
+                                + ("" if config.on_unhealthy == "raise" else
+                                   f"; gave up after {rollbacks} rollback(s)"))
+                        rollbacks += 1
+                        start, keep = mark
+                        del residuals[keep:], errors[keep:]
+                        del nnz_us[keep:], nnz_vs[keep:]
+                        max_nnz = _restore_stream_state(
+                            model, ckpt, u0, config, rollbacks)
+                        warnings.warn(
+                            f"streaming fit went unhealthy by chunk {idx}; "
+                            f"rolling back to chunk {start} with reseeded RNG "
+                            f"(attempt {rollbacks}/{config.max_rollbacks})",
+                            RuntimeWarning)
+                        replay = True
+                        break
+                    if boundary:
+                        ckpt.save(
+                            done,
+                            {"u": model.u_, "av": model._av_acc,
+                             "gv": model._gv_acc},
+                            history={
+                                "residual": [float(x) for x in residuals],
+                                "error": [float(x) for x in errors],
+                                "nnz_u": [int(x) for x in nnz_us],
+                                "nnz_v": [int(x) for x in nnz_vs],
+                                "max_nnz": int(max_nnz),
+                            },
+                            n_docs_seen=int(model.n_docs_seen_))
+                        mark = (done, len(residuals))
+                    if config.tol > 0.0 and float(r) <= config.tol:
+                        converged = True
+                        break
+        _add_stream_stats(stream_stats, stream)
 
     # frozen-U fold-in: the corpus loadings, streamed chunk-wise
-    v_full = _fold_in_streamed(model, source, config)
+    v_full = _fold_in_streamed(model, source, config, stream_stats)
     return FitResult(
         u=model.u_, v=v_full,
         residual=jnp.stack(residuals).astype(jnp.float32),
@@ -580,6 +605,7 @@ def solve_streaming(a: Matrix, config: NMFConfig, u0: jax.Array) -> FitResult:
         nnz_u=jnp.stack(nnz_us),
         nnz_v=jnp.stack(nnz_vs),
         error_granularity="chunk",
+        stream_stats=stream_stats,
     )
 
 

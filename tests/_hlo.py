@@ -9,6 +9,12 @@ ENGINE_SCOPES = ("als.v/product", "als.v/solve", "als.v/topk",
                  "als.error", "als.health")
 SCOPE = re.compile(r"/(als\.[uv]/(?:product|solve|topk)|als\.error"
                    r"|als\.health)(?:/|$)")
+#: the online engine's named scopes (``online_als_step``)
+ONLINE_SCOPES = ("online.v/product", "online.v/solve", "online.v/topk",
+                 "online.u/product", "online.u/solve", "online.u/topk",
+                 "online.health")
+ONLINE_SCOPE = re.compile(r"/(online\.[uv]/(?:product|solve|topk)"
+                          r"|online\.health)(?:/|$)")
 
 
 def op_name(line: str) -> str:
@@ -40,3 +46,17 @@ def loop_body(text: str) -> list:
             and re.search(r"jit\(als_nmf\)/while$", op_name(line))]
     assert len(scan) == 1, scan
     return comps[re.search(r"body=%([\w.\-]+)", scan[0]).group(1)]
+
+
+def dots(text: str) -> list:
+    """The matrix products of compiled HLO text: on a TPU a ``dot`` becomes
+    a ``convolution`` (or stays a ``dot``)."""
+    return [line for line in text.splitlines()
+            if re.search(r"\s(convolution|dot)\(", line)]
+
+
+def at_highest(line: str) -> bool:
+    """True where a product's operands contract at full float32 precision
+    (``Precision.HIGHEST``); at the default the compiler prints no
+    ``operand_precision``."""
+    return "operand_precision={highest,highest}" in line

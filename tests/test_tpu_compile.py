@@ -25,7 +25,7 @@ from repro.kernels.fused import bsr_spmm_gram
 from repro.kernels.gram import gram
 from repro.kernels.project_mask import project_mask
 
-from _hlo import SCOPE, loop_body, op_name
+from _hlo import SCOPE, at_highest, dots, loop_body, op_name
 
 CORPORA = ("reuters", "pubmed", "wikipedia")
 BM = BK = 128
@@ -167,3 +167,85 @@ def test_every_op_of_the_fit_loop_carries_a_scope(one_chip, monkeypatch):
     unscoped = [line.strip()[:160] for line in ops_of_body
                 if not SCOPE.search(op_name(line))]
     assert not unscoped, unscoped
+
+
+def _gram_spy(monkeypatch):
+    """Record the argument of every ``factor_gram`` call the estimator and
+    the solvers make."""
+    import repro.nmf.estimator as estimator
+    import repro.nmf.solvers as solvers
+    from repro.core.nmf import factor_gram
+
+    seen = []
+
+    def spy(x):
+        seen.append(jax.ShapeDtypeStruct(x.shape, x.dtype))
+        return factor_gram(x)
+
+    for module in (estimator, solvers):
+        monkeypatch.setattr(module, "factor_gram", spy)
+    return seen
+
+
+def _tiny_streamed_model():
+    import numpy as np
+    import scipy.sparse as sp
+
+    from repro.data.corpus import as_chunk_source
+    from repro.nmf import EnforcedNMF, NMFConfig, Sparsity
+    from repro.sparse import from_scipy
+
+    n, m, k = 256, 96, 5
+    a = sp.random(n, m, density=0.1, random_state=0, format="csr",
+                  dtype=np.float32)
+    model = EnforcedNMF(NMFConfig(k=k, solver="streaming", backend="jnp-csr",
+                                  sparsity=Sparsity(t_u=300, t_v=100)))
+    rng = np.random.default_rng(0)
+    model.u_ = jnp.asarray(rng.random((n, k), np.float32))
+    model.v_ = jnp.asarray(rng.random((m, k), np.float32))
+    model.n_features_, model._m_ref = n, m
+    return model, a, as_chunk_source(from_scipy(a), chunk_docs=m // 4)
+
+
+@pytest.mark.parametrize("site", ["transform", "fold_in", "seed_stats"])
+def test_stream_grams_contract_at_highest(one_chip, monkeypatch, site):
+    """The Grams of ``transform``, the streamed fit's fold-in and its seed
+    statistics are one ``factor_gram`` each, and the chip's compiler keeps
+    every product of it at full float32 precision (at XLA's default a TPU
+    product is one bfloat16 pass)."""
+    from repro.nmf.solvers import STREAM_STATS, _fold_in_streamed
+
+    model, a, source = _tiny_streamed_model()
+    seen = _gram_spy(monkeypatch)
+    if site == "transform":
+        model.transform(a)
+    elif site == "fold_in":
+        _fold_in_streamed(model, source, model.config,
+                          dict.fromkeys(STREAM_STATS, 0))
+    else:
+        model._seed_stats_streamed(source)
+    assert len(seen) == 1, seen
+    from repro.core.nmf import factor_gram
+
+    products = dots(_compiled_text(factor_gram, _sds(seen[0].shape,
+                                                     one_chip)))
+    assert products and all(at_highest(line) for line in products), products
+
+
+def test_the_batch_fit_takes_none_of_the_stream_grams(monkeypatch):
+    """A resident fit, the batch cells' path, seeds its statistics through
+    the fused kernel and never reaches ``factor_gram``: its program is the
+    one it was before the stream's Grams moved to full precision."""
+    import numpy as np
+    import scipy.sparse as sp
+
+    from repro.backend import get_backend
+    from repro.nmf import EnforcedNMF, NMFConfig, Sparsity
+
+    a = sp.random(256, 192, density=0.05, random_state=0, format="csr",
+                  dtype=np.float32)
+    op = get_backend("pallas-bsr").prepare(a, dtype=np.float32)
+    seen = _gram_spy(monkeypatch)
+    EnforcedNMF(NMFConfig(k=4, iters=2, tol=0.0, backend="pallas-bsr",
+                          sparsity=Sparsity(t_u=100, t_v=80))).fit(op)
+    assert seen == []
