@@ -57,11 +57,13 @@ class MatmulBackend(Protocol):
         """True when ``a`` is already this backend's native operand type."""
         ...
 
-    def prepare(self, a, dtype=None):
+    def prepare(self, a, dtype=None, stats=None):
         """Coerce arbitrary input (dense, scipy sparse, SpCSR, BSROperand)
         to this backend's native operand.  Host-side, called once at ingest;
         never materializes a dense matrix from sparse input unless the
-        backend itself is dense."""
+        backend itself is dense.  A backend that builds its operand from
+        the input adds the bytes it sent to the device to
+        ``stats["ingest_bytes"]`` where ``stats`` is given."""
         ...
 
     def matmul(self, a, v: jax.Array) -> jax.Array:

@@ -57,7 +57,7 @@ class JnpDenseBackend(LocalExecution):
     def accepts(self, a) -> bool:
         return isinstance(a, (jax.Array, np.ndarray))
 
-    def prepare(self, a, dtype=None):
+    def prepare(self, a, dtype=None, stats=None):
         if isinstance(a, jax.Array) and dtype is None:
             return a  # pass-through: legacy results stay bit-for-bit
         if isinstance(a, SpCSR):
@@ -91,7 +91,7 @@ class JnpCsrBackend(LocalExecution):
     def accepts(self, a) -> bool:
         return isinstance(a, SpCSR)
 
-    def prepare(self, a, dtype=None):
+    def prepare(self, a, dtype=None, stats=None):
         if isinstance(a, SpCSR):
             if dtype is not None and a.values.dtype != jnp.dtype(dtype):
                 return SpCSR(a.values.astype(dtype), a.cols, a.shape)

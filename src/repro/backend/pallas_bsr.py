@@ -68,18 +68,21 @@ class PallasBsrBackend(LocalExecution):
     def accepts(self, a) -> bool:
         return isinstance(a, BSROperand)
 
-    def prepare(self, a, dtype=None, bcap: int | None = None) -> BSROperand:
+    def prepare(self, a, dtype=None, bcap: int | None = None,
+                stats: dict | None = None) -> BSROperand:
         """Ingest dense / scipy-sparse / SpCSR / BSR input into the
         two-orientation BSR operand.  Sparse inputs never touch a dense
-        (n, m) matrix: scipy goes tile-wise via ``bsr_from_scipy`` and the
-        transposed copy is built tile-wise from the occupied tiles."""
+        (n, m) matrix: the host works on the non-zeros and the occupied
+        blocks, and both orientations' tiles are filled on the device
+        (:func:`repro.kernels.bsr.bsr_operand`).  ``stats["ingest_bytes"]``
+        grows by the bytes that went to the device."""
         if isinstance(a, BSROperand):
             return a
         if isinstance(a, SpCSR):
             a = to_scipy(a)  # nnz-proportional host round-trip
         tiles = self.tile_config(*a.shape)
         return bsr_operand(a, bm=tiles.bm, bk=tiles.bk, bcap=bcap,
-                           dtype=dtype)
+                           dtype=dtype, stats=stats)
 
     def matmul(self, a: BSROperand, v: jax.Array) -> jax.Array:
         return spmm(a.bsr, v)

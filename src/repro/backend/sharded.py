@@ -118,7 +118,7 @@ class ShardedBackend:
     def accepts(self, a) -> bool:
         return isinstance(a, ShardView)
 
-    def prepare(self, a, dtype=None):
+    def prepare(self, a, dtype=None, stats=None):
         if not isinstance(a, ShardView):
             raise TypeError(
                 "ShardedBackend consumes ShardView shards built inside a "

@@ -1,4 +1,4 @@
-"""Block-CSR (BSR) sparse format + host-side converters.
+"""Block-CSR (BSR) sparse format and its construction.
 
 TPU adaptation of the paper's sparse storage: the MXU consumes dense
 128x128 tiles, so instead of element-wise CSC (MATLAB) we store A as a set
@@ -15,16 +15,25 @@ tiles and block_col 0, contributing nothing to the product.
 ingest (memory 2x nnz-blocks — the standard trade for scatter-free TPU
 execution).  :class:`BSROperand` bundles the two orientations; it is the
 operand type the ``pallas-bsr`` matmul backend consumes.
+
+Every constructor goes through one function, :func:`_build`: the host works on
+the element COO and the occupied blocks only, and the tiles of each
+orientation are written on the device by one Pallas launch from the entries
+(:mod:`repro.kernels.fill`), so no tile volume is made, transposed or read
+back on the host.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.fill import fill_tiles
 
 
 @jax.tree_util.register_dataclass
@@ -86,49 +95,22 @@ class BSROperand:
 
 
 def bsr_from_dense(a: np.ndarray, bm: int = 128, bk: int = 128, bcap: int | None = None) -> BSR:
-    """Host-side conversion (numpy).  Pads n, m up to block multiples.
-
-    Fully vectorized: occupied blocks scatter into their slots through the
-    same :func:`_keep_top_per_group` machinery as :func:`bsr_from_scipy`,
-    so large dense fixtures ingest in numpy time rather than a Python
-    double loop.  An explicit ``bcap`` below a row-block's occupancy keeps
-    its ``bcap`` largest-Frobenius-norm blocks and warns (the scipy-ingest
-    truncation policy; the old loop silently kept the first ``bcap``).
-    """
+    """Dense ``(n, m)`` -> BSR, padding n, m up to block multiples: the
+    forward half of :func:`_build`, fed the array's
+    non-zeros.  An explicit ``bcap`` below a row-block's occupancy keeps
+    its ``bcap`` largest-Frobenius-norm blocks and warns."""
     a = np.asarray(a)
-    n, m = a.shape
-    n_pad = (-n) % bm
-    m_pad = (-m) % bk
-    ap = np.pad(a, ((0, n_pad), (0, m_pad)))
-    nrb, ncb = ap.shape[0] // bm, ap.shape[1] // bk
-    blocked = ap.reshape(nrb, bm, ncb, bk).transpose(0, 2, 1, 3)  # (nrb, ncb, bm, bk)
-    block_sq = (blocked.astype(np.float64) ** 2).sum(axis=(2, 3))  # (nrb, ncb)
-    occ_i, occ_j = np.nonzero(block_sq > 0)  # row-major: ascending j within i
-    cap = bcap
-    if cap is None:
-        cap = max(int(np.bincount(occ_i, minlength=nrb).max(initial=1)), 1)
-    keep, slots, counts = _keep_top_per_group(
-        occ_i, block_sq[occ_i, occ_j], nrb, cap)
-    if (counts > cap).any():
-        warnings.warn(
-            f"bsr_from_dense: {int((counts > cap).sum())} row-blocks exceed "
-            f"bcap={cap}; keeping the {cap} largest-Frobenius-norm "
-            "blocks per row-block",
-            stacklevel=2,
-        )
-    tiles = np.zeros((nrb, cap, bm, bk), dtype=a.dtype)
-    bcols = np.zeros((nrb, cap), dtype=np.int32)
-    i_k, j_k, s_k = occ_i[keep], occ_j[keep], slots[keep]
-    tiles[i_k, s_k] = blocked[i_k, j_k]
-    bcols[i_k, s_k] = j_k
-    return BSR(jnp.asarray(tiles), jnp.asarray(bcols), (n, m))
+    rows, cols, vals = _dense_coo(a)
+    (bsr,) = _build(rows, cols, vals, a.shape, bm, bk, bcap=bcap,
+                    transposed=False, who="bsr_from_dense")
+    return bsr
 
 
 def _keep_top_per_group(group_ids, sqnorms, ngroups: int, cap: int):
     """Rank items within each group by descending ``sqnorms``, keep the
     ``cap`` largest per group, and slot the survivors in ascending
-    original-index order (the layout invariant ``bsr_from_dense``
-    establishes: ascending block-col / source-row-block within a slot row).
+    original-index order (the layout invariant of every BSR here:
+    ascending block-col / source-row-block within a slot row).
 
     Returns ``(keep, slots, counts)``: a boolean keep mask over the items,
     the slot index per item (only meaningful where ``keep``), and the
@@ -154,54 +136,161 @@ def _keep_top_per_group(group_ids, sqnorms, ngroups: int, cap: int):
 def bsr_from_scipy(sp_matrix, bm: int = 128, bk: int = 128,
                    bcap: int | None = None, dtype=None) -> BSR:
     """Direct ``scipy.sparse -> BSR`` ingest, never materializing the dense
-    matrix: memory and work are proportional to nnz plus the stored-tile
-    volume.  This is the ingest path for real vectorizer corpora, where the
-    dense (n, m) matrix would not fit on the host.
+    matrix: the forward half of :func:`_build`.  The host
+    works on the non-zeros and the occupied blocks; the tiles are filled
+    on the device.  This is the ingest path for real vectorizer corpora,
+    where the dense (n, m) matrix would not fit on the host.
 
     ``bcap`` bounds the occupied-block slots per row-block; row-blocks with
     more occupied blocks keep the ``bcap`` largest by Frobenius norm (the
     top-t philosophy applied block-wise) and a warning reports how many
     row-blocks were truncated.
     """
+    rows, cols, vals = _scipy_coo(sp_matrix, dtype)
+    (bsr,) = _build(rows, cols, vals, sp_matrix.shape, bm, bk, bcap=bcap,
+                    transposed=False)
+    return bsr
+
+
+def _scipy_coo(sp_matrix, dtype=None):
+    """Row-major element COO of a scipy matrix: duplicates summed,
+    explicit zeros dropped, values cast to ``dtype`` after that."""
     coo = sp_matrix.tocoo()
     coo.sum_duplicates()
     coo.eliminate_zeros()
-    n, m = coo.shape
     data = coo.data if dtype is None else coo.data.astype(dtype)
-    nrb, ncb = -(-n // bm), -(-m // bk)
-    bi = coo.row // bm
-    bj = coo.col // bk
-    block_id = bi.astype(np.int64) * ncb + bj
-    uniq, inv = np.unique(block_id, return_inverse=True)
-    ubi = (uniq // ncb).astype(np.int64)
-    ubj = (uniq % ncb).astype(np.int32)
-    sqnorms = np.zeros(len(uniq), dtype=np.float64)
-    np.add.at(sqnorms, inv, data.astype(np.float64) ** 2)
-    cap = bcap
+    return coo.row, coo.col, data
+
+
+def _dense_coo(a: np.ndarray):
+    """Row-major element COO of a dense host array's non-zeros."""
+    rows, cols = np.nonzero(a)
+    return rows, cols, a[rows, cols]
+
+
+#: the per-entry arrays are padded to a multiple of this many entries (and
+#: so of :data:`repro.kernels.fill.BLOCK`), so every operand of one tile
+#: grid whose nnz falls in the same bucket (each chunk of a stream, in
+#: practice) reuses one compiled fill
+NNZ_BUCKET = 1 << 16
+
+_BLOCKS_CUT = ("{who}: {over} row-blocks exceed bcap={cap}; keeping the "
+               "{cap} largest-Frobenius-norm blocks per row-block")
+_TILES_CUT = ("bsr_transpose: {over} row-blocks of the transpose exceed "
+              "bcap={cap}; keeping the {cap} largest-Frobenius-norm tiles "
+              "per row-block")
+
+
+def _slot_table(groups, others, sqnorms, ngroups: int, cap, cut):
+    """One orientation's layout over occupied blocks, given each block's
+    row-block ``groups`` and block-col ``others``: keeps the ``cap``
+    largest-norm blocks of each row-block (``cap=None``: the fullest
+    row-block's count; ``cut(over=, cap=)`` words the warning when some
+    row-block overflows), slotted in the order the blocks come.  Returns
+    ``(cap, keep, tile, block_cols)``, ``tile`` being each block's flat
+    tile index ``row_block * cap + slot`` where kept, and ``ngroups * cap``
+    (out of bounds) where not."""
     if cap is None:
-        counts = np.bincount(ubi, minlength=nrb)
-        cap = max(int(counts.max(initial=1)), 1)
-    # on overflow keep the largest-norm blocks per row-block, slotted in
-    # ascending block-col order (uniq is sorted by (ubi, ubj), so the
-    # no-overflow layout matches bsr_from_dense exactly)
-    keep_block, slot, counts = _keep_top_per_group(ubi, sqnorms, nrb, cap)
-    if (counts > cap).any():
-        warnings.warn(
-            f"bsr_from_scipy: {int((counts > cap).sum())} row-blocks exceed "
-            f"bcap={cap}; keeping the {cap} largest-Frobenius-norm "
-            "blocks per row-block",
-            stacklevel=2,
-        )
-    tiles = np.zeros((nrb, cap, bm, bk), dtype=data.dtype)
-    bcols = np.zeros((nrb, cap), dtype=np.int32)
-    kept_uniq = keep_block[inv]
-    e_bi = bi[kept_uniq]
-    e_slot = slot[inv[kept_uniq]]
-    e_r = (coo.row[kept_uniq] % bm).astype(np.int64)
-    e_c = (coo.col[kept_uniq] % bk).astype(np.int64)
-    np.add.at(tiles, (e_bi, e_slot, e_r, e_c), data[kept_uniq])
-    bcols[ubi[keep_block], slot[keep_block]] = ubj[keep_block]
-    return BSR(jnp.asarray(tiles), jnp.asarray(bcols), (n, m))
+        cap = max(int(np.bincount(groups, minlength=ngroups).max(initial=1)),
+                  1)
+    keep, slots, counts = _keep_top_per_group(groups, sqnorms, ngroups, cap)
+    over = int((counts > cap).sum())
+    if over:
+        warnings.warn(cut(over=over, cap=cap), stacklevel=4)
+    block_cols = np.zeros((ngroups, cap), dtype=np.int32)
+    block_cols[groups[keep], slots[keep]] = others[keep]
+    return cap, keep, np.where(keep, groups * cap + slots, ngroups * cap), \
+        block_cols
+
+
+def _build(rows, cols, vals, shape, bm: int, bk: int, *, bcap=None,
+           forward: bool = True, transposed: bool = True,
+           who: str = "bsr_from_scipy", stats: dict | None = None):
+    """Build BSR orientations of the ``(n, m)`` matrix whose distinct
+    non-zeros are the element COO ``(rows, cols, vals)``.
+
+    The host works only on the entries and the occupied blocks: each
+    block's squared norm and, per orientation, the tile it lands in.
+    ``forward`` is A in ``(n/bm, bcap, bm, bk)`` tiles, ``bcap`` truncating
+    as :func:`bsr_from_scipy` says; ``transposed`` is A^T in ``(m/bk,
+    bcap_t, bk, bm)`` tiles from the kept forward blocks (all blocks when
+    ``forward`` is off, ``bcap`` then truncating as :func:`bsr_transpose`
+    says), in ascending source-row-block order.  The kept entries go to
+    the device in the first orientation's tile order, about 12 bytes an
+    entry: the value, the in-tile offset and, for a second orientation,
+    its own order of them; and, per orientation, where each tile's
+    entries start.  There :func:`~repro.kernels.fill.fill_tiles` writes
+    them into zero tiles, once an orientation.  ``stats["ingest_bytes"]`` grows by the bytes
+    uploaded.  The build is the host span ``bsr.ingest``."""
+    with jax.profiler.TraceAnnotation("bsr.ingest"):
+        n, m = shape
+        nrb, ncb = -(-n // bm), -(-m // bk)
+        block = (rows // bm).astype(np.int64) * ncb + cols // bk
+        uniq, inv = np.unique(block, return_inverse=True)
+        ubi, ubj = uniq // ncb, uniq % ncb
+        sqnorms = np.bincount(inv, weights=np.square(vals, dtype=np.float64),
+                              minlength=len(uniq))
+        r, c = (rows % bm).astype(np.int64), (cols % bk).astype(np.int64)
+        grids, keys, tiles, block_cols = [], [], [], []
+        kept = np.ones(len(uniq), dtype=bool)
+        if forward:
+            cap, kept, tile, bcols = _slot_table(
+                ubi, ubj, sqnorms, nrb, bcap,
+                functools.partial(_BLOCKS_CUT.format, who=who))
+            grids.append((nrb, cap, bm, bk))
+            tiles.append(tile)
+            keys.append((tile[inv] * bm + r) * bk + c)
+            block_cols.append(bcols)
+        if transposed:
+            # the transpose holds the kept forward blocks that hold a value
+            sub = np.flatnonzero(kept & (sqnorms > 0))
+            cap, kept_t, tile_t, bcols = _slot_table(
+                ubj[sub], ubi[sub], sqnorms[sub], ncb,
+                None if forward else bcap, _TILES_CUT.format)
+            kept = np.zeros(len(uniq), dtype=bool)
+            kept[sub] = kept_t
+            tile = np.full(len(uniq), ncb * cap, dtype=np.int64)
+            tile[sub] = tile_t
+            grids.append((ncb, cap, bk, bm))
+            tiles.append(tile)
+            keys.append((tile[inv] * bk + c) * bm + r)
+            block_cols.append(bcols)
+        # the entries every orientation keeps, in the first one's tile order
+        # (its tiles, and each tile's (row, col), ascending)
+        ent = np.flatnonzero(kept[inv] & (vals != 0))
+        ent = ent[np.argsort(keys[0][ent])]
+        nnz = len(ent)
+        bucket = max(-(-nnz // NNZ_BUCKET), 1) * NNZ_BUCKET
+        host = {"vals": np.zeros(bucket,
+                                 jax.dtypes.canonicalize_dtype(vals.dtype)),
+                "offs": np.zeros(bucket, np.int32), "perm": None,
+                "starts": [], "block_cols": block_cols}
+        host["vals"][:nnz] = vals[ent]
+        host["offs"][:nnz] = r[ent] * bk + c[ent]
+        order = np.arange(nnz)
+        if len(grids) == 2:
+            # the second orientation reads the entries in its own tile order
+            order = np.argsort(keys[1][ent])
+            host["perm"] = np.arange(bucket, dtype=np.int32)
+            host["perm"][:nnz] = order
+        for grid, tile, at in zip(grids, tiles, (np.arange(nnz), order)):
+            host["starts"].append(np.searchsorted(
+                tile[inv[ent[at]]], np.arange(grid[0] * grid[1] + 1)
+            ).astype(np.int32))
+        if stats is not None:
+            stats["ingest_bytes"] = (stats.get("ingest_bytes", 0) + sum(
+                x.nbytes for x in jax.tree_util.tree_leaves(host)))
+        dev = jax.device_put(host)
+        interpret = jax.default_backend() != "tpu"
+        out = []
+        for i, (grid, starts, bcols) in enumerate(
+                zip(grids, dev["starts"], dev["block_cols"])):
+            swap = i > 0 or not forward
+            out.append(BSR(fill_tiles(dev["vals"], dev["offs"], starts,
+                                      dev["perm"] if i else None, grid=grid,
+                                      swap=swap, interpret=interpret),
+                           bcols, (m, n) if swap else (n, m)))
+        return out
 
 
 def bsr_dot_uv(a: BSR, u: jax.Array, v: jax.Array) -> jax.Array:
@@ -253,61 +342,43 @@ def bsr_to_dense(a: BSR) -> jax.Array:
 
 
 def bsr_transpose(a: BSR, bcap: int | None = None) -> BSR:
-    """Build the transposed-format copy tile-wise (host-side, once at
-    ingest): every occupied tile (i, s) with block-col j becomes tile
-    ``tiles[i, s].T`` at row-block j with block-col i.  Work and memory are
-    proportional to the number of occupied tiles — the dense (n, m)
-    round-trip this replaces OOMed on exactly the large-A regime the paper
-    targets.
+    """The transposed-format copy of an existing BSR: its stored non-zeros
+    (:func:`bsr_to_coo`) through :func:`_build`'s transposed half.  Tile
+    ``(i, s)`` with block-col j lands at row-block j with block-col i, in
+    ascending i.  Work and memory are proportional to the stored tiles,
+    never the dense (n, m) matrix.
 
     An explicit ``bcap`` smaller than a destination row-block's occupancy
     keeps its ``bcap`` largest-Frobenius-norm tiles (the same truncation
     policy as :func:`bsr_from_scipy`) and warns with the truncated count.
     """
-    tiles = np.asarray(a.tiles)
-    bcols = np.asarray(a.block_cols)
-    nrb, _, bm, bk = tiles.shape
-    n, m = a.shape
-    ncb = -(-m // bk)
-    tile_sq = (tiles.astype(np.float64) ** 2).sum(axis=(2, 3))  # (nrb, bcap)
-    occ_i, occ_s = np.nonzero(tile_sq > 0)
-    occ_j = bcols[occ_i, occ_s].astype(np.int64)
-    if bcap is None:
-        bcap = max(int(np.bincount(occ_j, minlength=ncb).max(initial=1)), 1)
-    # keep the bcap largest-norm tiles per destination row-block, slotted
-    # in ascending source-row-block order (occupied tiles enumerate in
-    # (i, s) row-major order, matching bsr_from_dense's layout)
-    keep, slots, counts = _keep_top_per_group(
-        occ_j, tile_sq[occ_i, occ_s], ncb, bcap)
-    if (counts > bcap).any():
-        warnings.warn(
-            f"bsr_transpose: {int((counts > bcap).sum())} row-blocks of the "
-            f"transpose exceed bcap={bcap}; keeping the {bcap} "
-            "largest-Frobenius-norm tiles per row-block",
-            stacklevel=2,
-        )
-    tiles_t = np.zeros((ncb, bcap, bk, bm), dtype=tiles.dtype)
-    bcols_t = np.zeros((ncb, bcap), dtype=np.int32)
-    i_o, s_o, j_o = occ_i[keep], occ_s[keep], occ_j[keep]
-    tiles_t[j_o, slots[keep]] = tiles[i_o, s_o].transpose(0, 2, 1)
-    bcols_t[j_o, slots[keep]] = i_o
-    return BSR(jnp.asarray(tiles_t), jnp.asarray(bcols_t), (m, n))
+    rows, cols, vals = bsr_to_coo(a)
+    (bsr_t,) = _build(rows, cols, vals, a.shape, a.bm, a.bk, bcap=bcap,
+                      forward=False)
+    return bsr_t
 
 
 def bsr_operand(a, bm: int = 128, bk: int = 128, bcap: int | None = None,
-                dtype=None) -> BSROperand:
+                dtype=None, stats: dict | None = None) -> BSROperand:
     """Build the two-orientation :class:`BSROperand` from a dense array, a
     scipy sparse matrix, or an existing :class:`BSR` (transposed copy added
-    tile-wise)."""
+    by :func:`bsr_transpose`).  Both orientations of a new operand come from
+    one host pass over the non-zeros and are filled on the device
+    (:func:`_build`); ``stats["ingest_bytes"]`` grows by the bytes that went
+    to the device."""
     if isinstance(a, BSROperand):
         return a
     if isinstance(a, BSR):
-        bsr = a
-    elif hasattr(a, "tocoo"):  # scipy sparse, without a hard scipy import
-        bsr = bsr_from_scipy(a, bm=bm, bk=bk, bcap=bcap, dtype=dtype)
+        return BSROperand(a, bsr_transpose(a), a.shape)
+    if hasattr(a, "tocoo"):  # scipy sparse, without a hard scipy import
+        rows, cols, vals = _scipy_coo(a, dtype)
+        who = "bsr_from_scipy"
     else:
         a = np.asarray(a)
         if dtype is not None:
             a = a.astype(dtype)
-        bsr = bsr_from_dense(a, bm=bm, bk=bk, bcap=bcap)
-    return BSROperand(bsr, bsr_transpose(bsr), bsr.shape)
+        rows, cols, vals = _dense_coo(a)
+        who = "bsr_from_dense"
+    bsr, bsr_t = _build(rows, cols, vals, a.shape, bm, bk, bcap=bcap,
+                        who=who, stats=stats)
+    return BSROperand(bsr, bsr_t, bsr.shape)

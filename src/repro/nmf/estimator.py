@@ -96,7 +96,7 @@ class EnforcedNMF:
     # -- input coercion ------------------------------------------------------
 
     def _coerce(self, a: ArrayLike, chunkable: bool = False,
-                for_mesh: bool = False) -> Matrix:
+                for_mesh: bool = False, stats: Optional[dict] = None) -> Matrix:
         """Accept jax/numpy dense, SpCSR, BSROperand, or scipy sparse and
         ingest it for ``config.backend``.
 
@@ -115,7 +115,8 @@ class EnforcedNMF:
         the single-operand BSR conversion: the sharded ingest re-packs the
         corpus into *per-device* tile grids / CSR blocks
         (``engine.distribute``), so a whole-corpus ``BSROperand`` here
-        would be packed twice."""
+        would be packed twice.  ``stats`` collects the backend's ingest
+        counters (``Backend.prepare``)."""
         name = self.config.backend
         if for_mesh and isinstance(a, BSROperand):
             # every shard format re-packs the stored tiles per device
@@ -142,8 +143,9 @@ class EnforcedNMF:
             else:
                 return jnp.asarray(a, dtype=self.config.jnp_dtype)
         native = isinstance(a, (SpCSR, BSROperand, jax.Array))
+        counters = {} if stats is None else {"stats": stats}
         return get_backend(name).prepare(
-            a, dtype=None if native else self.config.jnp_dtype)
+            a, dtype=None if native else self.config.jnp_dtype, **counters)
 
     def _check_fitted(self):
         if self.u_ is None:
@@ -222,7 +224,7 @@ class EnforcedNMF:
             # pinning the corpus
             with jax.profiler.TraceAnnotation("nmf.seed_stats"):
                 if streamed:
-                    stats = self._seed_stats_streamed(a)
+                    stats = self._seed_stats_streamed(a, result.stream_stats)
                 else:
                     seed_backend = cfg.backend
                     if (seed_backend is not None
@@ -237,16 +239,18 @@ class EnforcedNMF:
             self._av_acc, self._gv_acc = stats.av, stats.gv
             return self
 
-    def _seed_stats_streamed(self, source) -> OnlineStats:
+    def _seed_stats_streamed(self, source,
+                             stream_stats: Optional[dict] = None) -> OnlineStats:
         """Full-corpus online statistics ``(A V, V^T V)`` from a chunk
         source, one chunk resident at a time: each chunk contributes
-        ``A_c V_c`` with its rows of the fitted loadings."""
+        ``A_c V_c`` with its rows of the fitted loadings.  Its ingest
+        counters add to ``stream_stats``, the fit's, where given."""
         v = self.v_
         av = None
         for i, (lo, hi) in enumerate(source.schedule):
             host = source.load(i)
             with jax.profiler.TraceAnnotation("nmf.stream.ingest"):
-                chunk = self._coerce(host)
+                chunk = self._coerce(host, stats=stream_stats)
             part = _matmul(chunk, v[lo:hi])
             av = part if av is None else av + part
         return OnlineStats(av=av, gv=factor_gram(v))

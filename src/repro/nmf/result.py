@@ -46,7 +46,9 @@ class FitResult:
     #: the streaming solver's prefetch counters, summed over its prefetched
     #: passes (the stream and the fold-in): ``packed`` chunks, ``pack_s``
     #: seconds inside the packer, ``stall_s`` seconds the fitting thread
-    #: waited for a chunk; ``None`` for the other solvers
+    #: waited for a chunk; and ``ingest_bytes``, the bytes the fit's chunk
+    #: ingests sent to the device over all its passes, the seed statistics'
+    #: included; ``None`` for the other solvers
     stream_stats: Optional[dict] = None
 
     @property

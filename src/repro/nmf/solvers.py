@@ -358,7 +358,7 @@ def solve_sequential(a: Matrix, config: NMFConfig, u0: jax.Array) -> FitResult:
     return FitResult.from_sequential_result(seq)
 
 
-def _make_packer(model):
+def _make_packer(model, stream_stats: dict):
     """The host-side pack function the stream (and its
     :class:`~repro.data.corpus.Prefetcher` worker) runs per chunk.
 
@@ -371,14 +371,18 @@ def _make_packer(model):
     varies with the data and which the device pads to a multiple of 128).
     Mesh runs do the full ahead-of-time pack: pad to the grid + per-device
     shard distribute (:meth:`EnforcedNMF._pack_mesh_chunk`), returning a
-    :class:`~repro.data.corpus.PackedChunk`."""
+    :class:`~repro.data.corpus.PackedChunk`.  A local ingest's counters
+    add to ``stream_stats``."""
     if model._mesh_streaming():
         return model._pack_mesh_chunk
-    return lambda chunk: jax.device_put(model._coerce(chunk))
+    return lambda chunk: jax.device_put(model._coerce(chunk,
+                                                      stats=stream_stats))
 
 
 #: the :class:`~repro.data.corpus.Prefetcher` counters a streamed fit sums
-#: over its prefetched passes into ``FitResult.stream_stats``
+#: over its prefetched passes into ``FitResult.stream_stats``, beside
+#: ``ingest_bytes``: the bytes its chunk ingests sent to the device, over
+#: all its passes (the seed statistics' included)
 STREAM_STATS = ("packed", "pack_s", "stall_s")
 
 
@@ -404,7 +408,8 @@ def _fold_in_streamed(model, source, config: NMFConfig,
         gram = factor_gram(u)
         parts = []
         with Prefetcher(range(len(source.schedule)),
-                        lambda i: model._coerce(source.load(i)),
+                        lambda i: model._coerce(source.load(i),
+                                                stats=stream_stats),
                         depth=config.prefetch_depth,
                         enabled=config.prefetch) as stream:
             for chunk in stream:
@@ -489,7 +494,8 @@ def solve_streaming(a: Matrix, config: NMFConfig, u0: jax.Array) -> FitResult:
     model.u_ = u0
     model.n_features_ = n
     model._m_ref = m  # t_v budgets are full-corpus; chunks rescale
-    pack = _make_packer(model)
+    stream_stats = dict.fromkeys(STREAM_STATS + ("ingest_bytes",), 0)
+    pack = _make_packer(model, stream_stats)
     ckpt = FitCheckpointer.from_config(config, source)
 
     # per-chunk metrics stay device scalars — only the tol check forces a
@@ -525,7 +531,6 @@ def solve_streaming(a: Matrix, config: NMFConfig, u0: jax.Array) -> FitResult:
 
     rollbacks = 0
     replay = True
-    stream_stats = dict.fromkeys(STREAM_STATS, 0)
     while replay:
         replay = False
         with Prefetcher(range(start, n_chunks),

@@ -252,6 +252,162 @@ def test_bsr_transpose_empty_and_huge_logical_shape():
 
 
 # ---------------------------------------------------------------------------
+# Device-side ingest against the numpy tile fill it replaced
+# ---------------------------------------------------------------------------
+
+def _numpy_operand(sp_matrix, bm, bk, bcap=None):
+    """The host ingest the device fill replaced, kept as the reference:
+    the forward tiles zero-filled and scattered on the host, then the
+    transpose ranked by its tiles' norms and transposed tile by tile.
+    Returns ``((tiles, block_cols), (tiles_t, block_cols_t))``."""
+    from repro.kernels.bsr import _keep_top_per_group
+
+    coo = sp_matrix.tocoo()
+    coo.sum_duplicates()
+    coo.eliminate_zeros()
+    n, m = coo.shape
+    data = coo.data.astype(np.float32)
+    nrb, ncb = -(-n // bm), -(-m // bk)
+    bi, bj = coo.row // bm, coo.col // bk
+    uniq, inv = np.unique(bi.astype(np.int64) * ncb + bj, return_inverse=True)
+    ubi, ubj = uniq // ncb, uniq % ncb
+    sq = np.zeros(len(uniq))
+    np.add.at(sq, inv, data.astype(np.float64) ** 2)
+    cap = bcap or max(int(np.bincount(ubi, minlength=nrb).max(initial=1)), 1)
+    keep, slot, _ = _keep_top_per_group(ubi, sq, nrb, cap)
+    tiles = np.zeros((nrb, cap, bm, bk), np.float32)
+    bcols = np.zeros((nrb, cap), np.int32)
+    k = keep[inv]
+    np.add.at(tiles, (bi[k], slot[inv[k]], coo.row[k] % bm, coo.col[k] % bk),
+              data[k])
+    bcols[ubi[keep], slot[keep]] = ubj[keep]
+    tile_sq = (tiles.astype(np.float64) ** 2).sum(axis=(2, 3))
+    oi, os_ = np.nonzero(tile_sq > 0)
+    oj = bcols[oi, os_].astype(np.int64)
+    cap_t = max(int(np.bincount(oj, minlength=ncb).max(initial=1)), 1)
+    keep_t, slot_t, _ = _keep_top_per_group(oj, tile_sq[oi, os_], ncb, cap_t)
+    tiles_t = np.zeros((ncb, cap_t, bk, bm), np.float32)
+    bcols_t = np.zeros((ncb, cap_t), np.int32)
+    j, s_t = oj[keep_t], slot_t[keep_t]
+    tiles_t[j, s_t] = tiles[oi[keep_t], os_[keep_t]].transpose(0, 2, 1)
+    bcols_t[j, s_t] = oi[keep_t]
+    return (tiles, bcols), (tiles_t, bcols_t)
+
+
+def _ingest_case(name):
+    """``(input, scipy view of it, bm, bk, bcap)`` of one parity case."""
+    rng = np.random.default_rng(11)
+    if name == "odd_shapes":
+        a = sps.csr_matrix(_rand_sparse(rng, 257, 129))
+        return a, a, 32, 32, None
+    if name == "empty_row_blocks":
+        d = _rand_sparse(rng, 200, 150)
+        d[32:96] = 0
+        d[160:] = 0
+        return sps.csr_matrix(d), sps.csr_matrix(d), 32, 32, None
+    if name == "ragged_last_col_block":
+        a = sps.csr_matrix(_rand_sparse(rng, 64, 100, density=0.2))
+        return a, a, 16, 32, None
+    if name == "duplicates":
+        # repeated coordinates sum; an explicit zero and a pair that sums
+        # to zero are no entries
+        rows = np.array([0, 0, 5, 5, 40, 40, 7, 70, 3])
+        cols = np.array([1, 1, 2, 2, 33, 33, 9, 60, 50])
+        vals = np.array([1.0, 2.5, 1.0, -1.0, 0.25, 0.5, 0.0, 3.0, 2.0],
+                        np.float32)
+        a = sps.coo_matrix((vals, (rows, cols)), shape=(72, 65))
+        return a, a, 32, 32, None
+    if name == "bcap_truncates":
+        d = np.zeros((64, 96), np.float32)
+        d[0, 0], d[1, 32], d[2, 64] = 1.0, 5.0, 3.0   # row-block 0: 3 blocks
+        d[33, 40], d[40, 70] = 2.0, 4.0               # row-block 1: 2 blocks
+        return sps.csr_matrix(d), sps.csr_matrix(d), 32, 32, 2
+    if name == "dense":
+        d = _rand_sparse(rng, 90, 70, density=0.1)
+        return d, sps.csr_matrix(d), 32, 32, None
+    assert name == "spcsr"
+    a = sps.csr_matrix(_rand_sparse(rng, 130, 97))
+    return from_scipy(a), a, 32, 32, None
+
+
+@pytest.mark.parametrize("case", [
+    "odd_shapes", "empty_row_blocks", "ragged_last_col_block", "duplicates",
+    "bcap_truncates", "dense", "spcsr"])
+def test_device_ingest_is_bitwise_the_numpy_fill(case):
+    """Both orientations' tiles and block columns, bit for bit, as the
+    host fill built them; an explicit ``bcap`` truncates with the same
+    warning, and the transpose holds the kept tiles only."""
+    from repro.backend.pallas_bsr import PallasBsrBackend
+
+    a, as_scipy, bm, bk, bcap = _ingest_case(case)
+    with warnings.catch_warnings(record=True) as seen:
+        warnings.simplefilter("always")
+        if isinstance(a, SpCSR):
+            op = PallasBsrBackend(bm=bm, bk=bk).prepare(a)
+        else:
+            op = bsr_operand(a, bm=bm, bk=bk, bcap=bcap, dtype=np.float32)
+    said = [str(w.message) for w in seen]
+    if bcap is None:
+        assert not said
+    else:
+        assert said == [
+            "bsr_from_scipy: 1 row-blocks exceed bcap=2; keeping the 2 "
+            "largest-Frobenius-norm blocks per row-block"]
+    want = _numpy_operand(as_scipy, bm, bk, bcap)
+    n, m = as_scipy.shape
+    assert op.bsr.shape == (n, m) and op.bsr_t.shape == (m, n)
+    for got, (tiles, bcols) in zip((op.bsr, op.bsr_t), want):
+        assert got.tiles.dtype == np.float32
+        np.testing.assert_array_equal(
+            np.asarray(got.tiles).view(np.uint32), tiles.view(np.uint32))
+        np.testing.assert_array_equal(np.asarray(got.block_cols), bcols)
+
+
+def _full_grid_chunk(rng, extra):
+    """A 256 x 64 matrix with every 32 x 32 block occupied (so one grid and
+    one ``bcap`` in both orientations) and ``extra`` more entries."""
+    d = np.zeros((256, 64), np.float32)
+    d[::32, ::32] = 1.0
+    flat = rng.choice(d.size, size=extra, replace=False)
+    d.flat[flat] += rng.random(extra).astype(np.float32) + 0.5
+    return sps.csr_matrix(d)
+
+
+def test_same_grid_operands_of_other_nnz_compile_nothing():
+    """The fill compiles once for a tile grid and an nnz bucket: a second
+    chunk of the same grid with another nnz reuses it."""
+    from repro.analysis import recompile_guard
+
+    rng = np.random.default_rng(3)
+    first, second = _full_grid_chunk(rng, 500), _full_grid_chunk(rng, 900)
+    assert first.nnz != second.nnz
+    bsr_operand(first, bm=32, bk=32)
+    with recompile_guard() as counter:
+        op = bsr_operand(second, bm=32, bk=32)
+        jax.block_until_ready(op)
+    assert counter.count == 0
+    np.testing.assert_array_equal(np.asarray(bsr_to_dense(op.bsr)),
+                                  second.toarray())
+
+
+def test_ingest_uploads_entries_not_tiles():
+    """What goes to the device is the padded entries (value, in-tile offset,
+    the transpose's order) and, per orientation, where each tile's entries
+    start, not the tile volume: ``ingest_bytes`` counts it."""
+    from repro.kernels.bsr import NNZ_BUCKET
+
+    rng = np.random.default_rng(4)
+    a = sps.csr_matrix(_rand_sparse(rng, 4096, 1024, density=0.01))
+    stats = {}
+    op = bsr_operand(a, bm=128, bk=128, stats=stats)
+    starts = 4 * sum(b.nrb * b.bcap + 1 for b in (op.bsr, op.bsr_t))
+    block_cols = 4 * (op.bsr.block_cols.size + op.bsr_t.block_cols.size)
+    assert stats == {"ingest_bytes": 12 * NNZ_BUCKET + starts + block_cols}
+    assert stats["ingest_bytes"] < (op.bsr.tiles.nbytes
+                                    + op.bsr_t.tiles.nbytes) / 10
+
+
+# ---------------------------------------------------------------------------
 # Sparse-ingest truncation policy (the corpus-corruption bugfixes)
 # ---------------------------------------------------------------------------
 

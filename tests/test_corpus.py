@@ -175,6 +175,49 @@ def test_second_streamed_fit_compiles_nothing(corpus, tmp_path):
     assert model.u_ is not None
 
 
+def test_second_streamed_fit_on_pallas_bsr_compiles_nothing(corpus,
+                                                             tmp_path):
+    """Every chunk of a stream ingests into one tile grid and one nnz
+    bucket, so the device-side tile fill compiles with the first fit and
+    a second fit from disk compiles nothing, ingest included."""
+    out = write_corpus(corpus, tmp_path / "cc", chunk_docs=16)
+    _fit(str(out), backend="pallas-bsr")
+    with recompile_guard() as counter:
+        model = _fit(str(out), backend="pallas-bsr")
+    assert counter.count == 0
+    assert model.result_.stream_stats["ingest_bytes"] > 0
+
+
+def test_streamed_fit_uploads_a_tenth_of_its_tiles(tmp_path, monkeypatch):
+    """``stream_stats["ingest_bytes"]`` counts what the fit's chunk ingests
+    sent to the device, over the stream, the fold-in and the seed
+    statistics: the entries and block tables, under a tenth of the tile
+    volume they built there."""
+    import scipy.sparse as sp
+
+    import repro.kernels.bsr as bsr_mod
+
+    built = []
+    build = bsr_mod._build
+
+    def spy(*args, **kw):
+        out = build(*args, **kw)
+        built.append(sum(b.tiles.nbytes for b in out))
+        return out
+
+    monkeypatch.setattr(bsr_mod, "_build", spy)
+    a = sp.random(4096, 1024, density=0.004, random_state=2, format="csr",
+                  dtype=np.float32)
+    out = write_corpus(a, tmp_path / "wide", chunk_docs=512)
+    model = EnforcedNMF(NMFConfig(
+        k=4, iters=1, solver="streaming", chunk_docs=512,
+        sparsity=Sparsity(t_u=400, t_v=200),
+        backend="pallas-bsr")).fit(str(out))
+    assert len(built) == 3 * 2  # three passes over two chunks
+    ingest = model.result_.stream_stats["ingest_bytes"]
+    assert 0 < ingest < sum(built) / 10
+
+
 # ---------------------------------------------------------------------------
 # the prefetcher in isolation
 # ---------------------------------------------------------------------------

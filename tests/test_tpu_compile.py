@@ -122,6 +122,32 @@ def test_paper_widths_take_the_fused_path(corpus, transposed):
 
 
 @pytest.mark.parametrize("corpus", CORPORA)
+@pytest.mark.parametrize("transposed", [False, True],
+                         ids=["term_major", "doc_major"])
+def test_tile_fill_compiles_in_place(one_chip, corpus, transposed):
+    """Ingest's device-side fill of a full tile grid from 262,144 entries
+    (the ``bsr_fill`` kernel, after the transpose's gather of the entries)
+    needs no temporary of tile volume: the chip holds the tiles, the
+    entries and a few words an entry, nothing more."""
+    from repro.kernels.bsr import NNZ_BUCKET
+    from repro.kernels.fill import fill_tiles
+
+    cfg = NMF_CONFIGS[corpus]
+    nrb, ncb = -(-cfg["n_terms"] // BM), -(-cfg["n_docs"] // BK)
+    grid = (ncb, nrb, BK, BM) if transposed else (nrb, ncb, BM, BK)
+    entries = 4 * NNZ_BUCKET
+    order = _sds((entries,), one_chip, jnp.int32) if transposed else None
+    compiled = fill_tiles.lower(
+        _sds((entries,), one_chip), _sds((entries,), one_chip, jnp.int32),
+        _sds((nrb * ncb + 1,), one_chip, jnp.int32), order, grid=grid,
+        swap=transposed, interpret=False).compile()
+    assert _launches(compiled.as_text()) == 1
+    memory = compiled.memory_analysis()
+    assert memory.output_size_in_bytes == 4 * nrb * ncb * BM * BK
+    assert memory.temp_size_in_bytes <= 32 * entries
+
+
+@pytest.mark.parametrize("corpus", CORPORA)
 @pytest.mark.parametrize("side", ["terms", "docs"])
 def test_gram_compiles(one_chip, corpus, side):
     cfg = NMF_CONFIGS[corpus]
