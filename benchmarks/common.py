@@ -1,15 +1,10 @@
 """Shared helpers for the paper-figure benchmarks."""
 from __future__ import annotations
 
-import time
-
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from repro.core import init_u0
 from repro.data import synthetic_journal_corpus
-from repro.sparse import to_dense
 
 
 def reuters_like(seed=0):
@@ -29,15 +24,6 @@ def pubmed_like(seed=0, small=False):
     return synthetic_journal_corpus(
         n_terms=20112, n_docs=7510, n_journals=5, terms_per_doc=90, seed=seed
     )
-
-
-def timed(fn, *args, repeats=3, **kw):
-    fn(*args, **kw)  # compile
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        out = fn(*args, **kw)
-        jax.block_until_ready(out)
-    return (time.perf_counter() - t0) / repeats, out
 
 
 def u0_for(a, k, seed=2, nnz=None):

@@ -364,18 +364,6 @@ def _kernel_targets() -> List[IRTarget]:
     out.append(IRTarget(
         name="kernel:project_mask", kind="kernel", trace=trace_mask,
         operand_bytes=_nbytes(x), budget_key="kernel:project_mask"))
-
-    q = _sds((1, 2, 512, 64))
-
-    def trace_flash():
-        from repro.kernels.flash_attention import flash_attention
-
-        return jax.make_jaxpr(
-            lambda q, k, v: flash_attention(q, k, v, causal=True))(q, q, q)
-
-    out.append(IRTarget(
-        name="kernel:flash_attention", kind="kernel", trace=trace_flash,
-        operand_bytes=_nbytes(q) * 3, budget_key="kernel:flash_attention"))
     return out
 
 

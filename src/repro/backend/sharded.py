@@ -445,7 +445,7 @@ def make_sharded_als(
     v over cols, replicated scalar traces).  ``sparsify_u`` / ``sparsify_v``
     should be mesh-aware (:class:`repro.core.topk.DistTopK`) or ``None``.
     ``run.shard_fn(iters)`` exposes the un-jitted shard-mapped callable for
-    AOT lowering (the pod dry-run).
+    tracing and AOT lowering (the IR analysis traces it).
 
     The jitted step donates ``u0`` (in-place factor rotation across
     tol-chunked calls); pass a fresh or mesh-resharded array per call —

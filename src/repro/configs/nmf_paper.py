@@ -7,6 +7,4 @@ NMF_CONFIGS = {
     "wikipedia": dict(n_terms=143462, n_docs=12439, k=5, iters=50),
     # PubMed journals: 20,112 terms x 7,510 abstracts, 5 topics (Fig. 4-6, 9)
     "pubmed": dict(n_terms=20112, n_docs=7510, k=5, iters=50, n_journals=5),
-    # "Large" production-scale synthetic target for the distributed dry-run
-    "large-synthetic": dict(n_terms=4_000_000, n_docs=1_000_000, k=256, iters=20),
 }

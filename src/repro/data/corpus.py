@@ -417,7 +417,7 @@ class Prefetcher:
         #: ``pack_s`` wall time inside ``pack`` (the ingest work),
         #: ``stall_s`` time the consumer spent blocked waiting for a chunk —
         #: ``1 - stall_s / pack_s`` is the fraction of ingest wall time the
-        #: double-buffering hid under compute (bench_ingest's overlap gate)
+        #: double-buffering hid under compute
         #: — plus ``retries`` (I/O retry attempts) and ``skipped`` (chunks
         #: dropped via the skip hatch)
         self.stats = {"packed": 0, "max_queued": 0, "pack_s": 0.0,

@@ -4,9 +4,7 @@ from repro.kernels.ops import (
     bsr_to_dense, bsr_transpose,
     spmm, spmm_t, fused_project_mask, gram_matrix,
 )
-from repro.kernels.flash_attention import flash_attention
 
 __all__ = ["BSR", "BSROperand", "bsr_from_dense", "bsr_from_scipy",
            "bsr_operand", "bsr_to_dense", "bsr_transpose",
-           "spmm", "spmm_t", "fused_project_mask", "gram_matrix",
-           "flash_attention"]
+           "spmm", "spmm_t", "fused_project_mask", "gram_matrix"]

@@ -17,11 +17,10 @@ Tile sizes used to be hand-picked constants (``kb=128`` in ``bsr_spmm``,
   both the separate-spmm and the fused spmm+gram kernels fits the 16 MiB
   VMEM budget.  Illegal candidates are never timed.
 * :func:`autotune` — the sweep itself: builds a synthetic BSR operand per
-  candidate, wall-clock times the fused and separate kernels (the same
-  block-until-ready protocol as ``benchmarks/bench_backends.py``), scores
-  each candidate against the analytic roofline bound (the
-  ``benchmarks/roofline.py`` constants), and returns the winner as a
-  ledger entry.  Off-TPU this is interpret-mode-safe: without ``force``
+  candidate, wall-clock times the fused and separate kernels
+  (block-until-ready), scores each candidate against the analytic roofline
+  bound (the TPU v5e peaks below), and returns the winner as a ledger
+  entry.  Off-TPU this is interpret-mode-safe: without ``force``
   the sweep is skipped and the defaults are recorded as a fallback entry,
   so CI never commits interpret-mode timings as tuning facts.
 
@@ -61,9 +60,7 @@ __all__ = [
 #: with repro.analysis.ir.passes.pallas_tiles.VMEM_BUDGET
 VMEM_BUDGET = 16 * 1024 * 1024
 
-#: analytic roofline constants, mirroring benchmarks/roofline.py (imported
-#: lazily there; duplicated here so library code never imports the
-#: benchmark harness)
+#: analytic roofline constants: TPU v5e bf16 peak FLOP/s and HBM bandwidth
 PEAK_FLOPS = 197e12
 HBM_BW = 819e9
 
@@ -291,9 +288,9 @@ def _timed_us(fn, *args, repeats: int = 3) -> float:
 def _roofline_us(n: int, m: int, k: int, bm: int, bk: int, bcap: int,
                  itemsize: int = 4) -> float:
     """Analytic lower bound for the fused half-step product on this device
-    class: max(compute, memory) time from the benchmarks/roofline.py
-    constants.  The sweep records it next to the measured numbers so a
-    ledger entry documents how far off the roof it sits."""
+    class: max(compute, memory) time from the roofline constants above.
+    The sweep records it next to the measured numbers so a ledger entry
+    documents how far off the roof it sits."""
     nrb = -(-n // bm)
     flops = 2.0 * nrb * bcap * bm * bk * k       # spmm MXU work
     flops += 2.0 * nrb * bcap * bk * k * k       # gram accumulate

@@ -1,24 +1,11 @@
-"""Production mesh definitions.
+"""The device mesh the sharded NMF engine runs on.
 
-A function (not module-level constant) so importing never touches jax
-device state.  Target: TPU v5e pods — 16x16 = 256 chips per pod; the
-multi-pod mesh adds a leading "pod" axis (2 pods = 512 chips) connected
-over DCN, used for pure data parallelism.
+A function (not a module-level constant) so importing never touches jax
+device state.
 """
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_local_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh for tests on however many devices exist."""
-    return jax.make_mesh((data, model), ("data", "model"))
 
 
 def make_nmf_mesh(rows: int, cols: int) -> jax.sharding.Mesh:
@@ -26,7 +13,7 @@ def make_nmf_mesh(rows: int, cols: int) -> jax.sharding.Mesh:
     rows shard U / A's row blocks, cols shard V / A's column blocks.  This
     is the single construction point ``NMFConfig.mesh_shape`` lowers
     through (solvers, benchmarks, and tests all come here), so swapping in
-    a production pod topology is a one-line change."""
+    another topology is a one-line change."""
     import numpy as np
 
     devices = jax.devices()

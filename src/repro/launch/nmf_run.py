@@ -1,11 +1,5 @@
-"""NMF drivers: real runs (paper-scale synthetic corpora) and the
-production-mesh dry-run of the distributed enforced-sparsity ALS.
-
-Dry-run (the paper's "large" workload on 256/512 chips):
-    PYTHONPATH=src python -m repro.launch.dryrun --nmf [--multi-pod]
-(launch/dryrun.py imports nmf_dryrun_cell from here)
-
-Real run (any size that fits one host), through the unified estimator:
+"""NMF driver: real runs on paper-scale synthetic corpora through the
+unified estimator (any size that fits one host):
     PYTHONPATH=src python -m repro.launch.nmf_run --config pubmed --t-u 5000
     PYTHONPATH=src python -m repro.launch.nmf_run --config reuters \
         --solver sequential --sparsity "t_u=55,t_v=2000,mode=global"
@@ -19,105 +13,10 @@ from __future__ import annotations
 
 import argparse
 import time
-from typing import Dict
 
 import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import NMF_CONFIGS
-
-
-def nmf_input_specs(n: int, m: int, k: int, cap: int, cap_t: int,
-                    r: int, c: int):
-    """ShapeDtypeStruct stand-ins for the distributed factorization."""
-    f32, i32 = jnp.float32, jnp.int32
-    sds = jax.ShapeDtypeStruct
-    n_loc, m_loc = n // r, m // c
-    return (
-        sds((r, c, n_loc, cap), f32),      # values
-        sds((r, c, n_loc, cap), i32),      # cols
-        sds((r, c, m_loc, cap_t), f32),    # values_t
-        sds((r, c, m_loc, cap_t), i32),    # cols_t
-        sds((n, k), f32),                  # u0
-    )
-
-
-def nmf_dryrun_cell(mesh: jax.sharding.Mesh, *,
-                    n: int = 4_000_000, m: int = 1_000_000, k: int = 256,
-                    nnz_per_row: int = 256, iters: int = 20,
-                    t_frac: float = 0.02) -> Dict:
-    """Lower + compile the paper's Alg. 2 at production scale on ``mesh`` —
-    the *unified* ALS engine shard_mapped via ``make_sharded_als`` (the
-    exact code path ``solver="distributed"`` executes), not a separate
-    distributed loop.
-
-    Capacity sizing: row nonzeros spread over C column blocks with 2x skew
-    margin; transpose orientation likewise (col nnz = n*nnz/m).
-    """
-    from repro.backend.sharded import make_sharded_als
-    from repro.core.nmf import NMFResult
-    from repro.core.topk import DistTopK
-
-    axes = mesh.axis_names
-    rows_axes = tuple(a for a in ("pod", "data") if a in axes)
-    r = 1
-    for a in rows_axes:
-        r *= mesh.shape[a]
-    c = mesh.shape["model"]
-    cap = max(2 * nnz_per_row // c, 4)
-    col_nnz = n * nnz_per_row // m
-    cap_t = max(2 * col_nnz // r, 4)
-    t_u = int(n * k * t_frac)
-    t_v = int(m * k * t_frac)
-
-    run = make_sharded_als(
-        mesh, rows_axes, "model",
-        sparsify_u=DistTopK(t_u, rows_axes),
-        sparsify_v=DistTopK(t_v, ("model",)),
-        track_error=False,
-    )
-    _, u_spec, v_spec = run.specs
-    specs = nmf_input_specs(n, m, k, cap, cap_t, r, c)
-    shardings = tuple(
-        NamedSharding(mesh, s) for s in (*run.leaf_specs, u_spec)
-    )
-    rep = NamedSharding(mesh, P())
-    out_shardings = NMFResult(
-        u=NamedSharding(mesh, u_spec), v=NamedSharding(mesh, v_spec),
-        residual=rep, error=rep, max_nnz=rep, nnz_u=rep, nnz_v=rep,
-        health=rep,
-    )
-    t0 = time.time()
-    with jax.set_mesh(mesh):
-        jitted = jax.jit(  # repro: allow[jit-cache] one-shot benchmark harness; jitted once then AOT-lowered for the memory analysis
-            run.shard_fn(iters),
-            in_shardings=shardings,
-            out_shardings=out_shardings,
-            # u0 rotates in place like the production engine's jit — the
-            # memory analysis below then reports the aliased bytes
-            donate_argnums=(4,),
-        )
-        lowered = jitted.lower(*specs)
-        compiled = lowered.compile()
-    ca = compiled.cost_analysis() or {}
-    ma = compiled.memory_analysis()
-    rec = {
-        "arch": "nmf-large-synthetic",
-        "shape": f"n{n}_m{m}_k{k}_iters{iters}",
-        "mesh": "x".join(map(str, mesh.devices.shape)),
-        "status": "ok",
-        "flops": ca.get("flops", 0.0),
-        "bytes_accessed": ca.get("bytes accessed", 0.0),
-        "argument_bytes": getattr(ma, "argument_size_in_bytes", 0),
-        "temp_bytes": getattr(ma, "temp_size_in_bytes", 0),
-        "output_bytes": getattr(ma, "output_size_in_bytes", 0),
-        "alias_bytes": getattr(ma, "alias_size_in_bytes", 0),
-        "compile_s": round(time.time() - t0, 1),
-    }
-    rec["bytes_per_device"] = (rec["argument_bytes"] + rec["output_bytes"]
-                               + rec["temp_bytes"] - rec["alias_bytes"])
-    return rec, lowered, compiled
 
 
 def main(argv=None):

@@ -625,7 +625,7 @@ def solve_distributed(a: Matrix, config: NMFConfig, u0: jax.Array) -> FitResult:
     semantics) exactly like the single-device solvers.
 
     The default 1x1 mesh runs anywhere (CPU included) through the same
-    shard_map code path the pod dry-run lowers; larger meshes need
+    shard_map code path as every mesh; larger meshes need
     ``rows * cols`` visible devices and shapes divisible by the grid.
     ``SpCSR`` input is sharded directly from the padded-CSR arrays —
     nnz-proportional host work, no dense (n, m) driver allocation; dense

@@ -1,4 +1,3 @@
-from repro.serving.engine import Request, ServingEngine
 from repro.serving.topics import TopicRequest, TopicServer
 
-__all__ = ["Request", "ServingEngine", "TopicRequest", "TopicServer"]
+__all__ = ["TopicRequest", "TopicServer"]

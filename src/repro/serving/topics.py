@@ -1,11 +1,11 @@
 """Topic-inference serving endpoint over a fitted :class:`EnforcedNMF`.
 
-The NMF analogue of the LM ``ServingEngine``: requests carry a bag-of-words
-document (sparse ``(term_id, weight)`` pairs); the server micro-batches them
-into one padded-CSR matrix per tick and folds the whole batch into the fitted
-topic space with a single frozen-``U`` ``transform`` pass — so serving cost
-per tick is one (k x k) solve plus one sparse matmul regardless of how many
-documents share the batch.
+Requests carry a bag-of-words document (sparse ``(term_id, weight)``
+pairs); the server micro-batches them into one padded-CSR matrix per tick
+and folds the whole batch into the fitted topic space with a single
+frozen-``U`` ``transform`` pass — so serving cost per tick is one (k x k)
+solve plus one sparse matmul regardless of how many documents share the
+batch.
 
 Continuous refresh: served documents accumulate in a buffer and
 :meth:`TopicServer.refresh` streams them back into the model through one

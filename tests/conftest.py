@@ -1,8 +1,8 @@
 import jax
 import pytest
 
-# Keep default 1-device CPU config — dry-run tests spawn subprocesses with
-# their own XLA_FLAGS; nothing here may set device-count flags.
+# Keep default 1-device CPU config — multi-device tests spawn subprocesses
+# with their own XLA_FLAGS; nothing here may set device-count flags.
 jax.config.update("jax_enable_x64", False)
 
 

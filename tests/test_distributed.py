@@ -1,4 +1,4 @@
-"""Distributed NMF + compression tests.  Multi-device cases run in a
+"""Distributed NMF tests.  Multi-device cases run in a
 subprocess with --xla_force_host_platform_device_count (the main process
 keeps 1 device so other tests see the default config).
 
@@ -101,38 +101,6 @@ def test_dist_als_multipod_axes():
     """)
     out = json.loads(run_with_devices(8, code).strip().splitlines()[-1])
     assert out["finite"] and out["err"] < 1.0
-
-
-def test_compressed_grads_error_feedback():
-    """Top-k compressed DP grads + error feedback: compressed-summed grad +
-    residual error == uncompressed grad (conservation property)."""
-    code = textwrap.dedent("""
-        import jax, jax.numpy as jnp, numpy as np, json
-        from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro.training.compression import make_compressed_grad_fn, init_error_state
-        mesh = jax.make_mesh((4,), ("data",))
-        def loss_fn(params, batch):
-            pred = batch["x"] @ params["w"]
-            return jnp.mean((pred - batch["y"]) ** 2)
-        params = {"w": jnp.asarray(np.random.default_rng(0).standard_normal((8, 4)), jnp.float32)}
-        batch = {"x": jnp.asarray(np.random.default_rng(1).standard_normal((16, 8)), jnp.float32),
-                 "y": jnp.asarray(np.random.default_rng(2).standard_normal((16, 4)), jnp.float32)}
-        with jax.set_mesh(mesh):
-            gf = make_compressed_grad_fn(loss_fn, mesh, ("data",), density=0.25)
-            err = init_error_state(params, 4)
-            loss, g, err2 = gf(params, batch, err)
-        # conservation: mean_dp(g_sparse) + mean_dp(err) == mean_dp(g_full)
-        full = jax.grad(loss_fn)(params, batch)
-        recon = g["w"] + jnp.mean(err2["w"], axis=0)
-        print(json.dumps({
-            "max_diff": float(jnp.max(jnp.abs(recon - full["w"]))),
-            "loss": float(loss),
-            "sparse_frac": float(jnp.mean((g["w"] != 0).astype(jnp.float32))),
-        }))
-    """)
-    out = json.loads(run_with_devices(4, code).strip().splitlines()[-1])
-    assert out["max_diff"] < 1e-5
-    assert out["sparse_frac"] <= 1.0
 
 
 def test_single_device_shard_map_paths():

@@ -227,70 +227,3 @@ def test_fused_backend_matches_unfused_end_to_end():
     np.testing.assert_allclose(np.asarray(results["pallas-bsr"].v),
                                np.asarray(results["pallas-bsr-unfused"].v),
                                rtol=1e-4, atol=1e-4)
-
-
-# ---------------------------------------------------------------------------
-# Flash attention kernel
-# ---------------------------------------------------------------------------
-
-def _flash_oracle(q, k, v, causal, groups):
-    kf = jnp.repeat(k, groups, axis=1)
-    vf = jnp.repeat(v, groups, axis=1)
-    s = jnp.einsum("bhsd,bhtd->bhst", q, kf) / jnp.sqrt(q.shape[-1])
-    if causal:
-        sq, t = s.shape[-2], s.shape[-1]
-        mask = jnp.arange(sq)[:, None] >= jnp.arange(t)[None, :]
-        s = jnp.where(mask, s, -jnp.inf)
-    p = jax.nn.softmax(s.astype(jnp.float32), -1)
-    return jnp.einsum("bhst,bhtd->bhsd", p, vf.astype(jnp.float32)).astype(q.dtype)
-
-
-@pytest.mark.parametrize("b,h,hkv,s,t,hd,causal", [
-    (2, 4, 4, 128, 128, 32, True),
-    (1, 8, 2, 256, 256, 64, True),
-    (2, 4, 2, 64, 192, 32, False),
-    (1, 2, 1, 96, 96, 16, True),
-])
-def test_flash_attention_vs_oracle(b, h, hkv, s, t, hd, causal):
-    from repro.kernels.flash_attention import flash_attention
-    key = jax.random.PRNGKey(b + s)
-    q = jax.random.normal(key, (b, h, s, hd), jnp.float32)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (b, hkv, t, hd))
-    v = jax.random.normal(jax.random.fold_in(key, 2), (b, hkv, t, hd))
-    out = flash_attention(q, k, v, causal=causal, bq=64, bk=64,
-                          groups=h // hkv, interpret=True)
-    expect = _flash_oracle(q, k, v, causal, h // hkv)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
-                               rtol=2e-3, atol=2e-3)
-
-
-def test_flash_attention_bf16():
-    from repro.kernels.flash_attention import flash_attention
-    key = jax.random.PRNGKey(9)
-    q = jax.random.normal(key, (1, 2, 128, 32)).astype(jnp.bfloat16)
-    k = jax.random.normal(jax.random.fold_in(key, 1), (1, 2, 128, 32)).astype(jnp.bfloat16)
-    v = jax.random.normal(jax.random.fold_in(key, 2), (1, 2, 128, 32)).astype(jnp.bfloat16)
-    out = flash_attention(q, k, v, causal=True, bq=64, bk=64, interpret=True)
-    expect = _flash_oracle(q.astype(jnp.float32), k.astype(jnp.float32),
-                           v.astype(jnp.float32), True, 1)
-    np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
-                               np.asarray(expect), rtol=5e-2, atol=5e-2)
-
-
-def test_model_attention_flash_path_matches():
-    """common.attention with the flash kernel enabled == XLA path."""
-    from repro.models import common
-    from repro.configs import ARCHS, smoke_config
-    cfg = smoke_config(ARCHS["llama3.2-1b"])
-    key = jax.random.PRNGKey(3)
-    p = common.init_attention(key, cfg)
-    x = jax.random.normal(key, (2, 64, cfg.d_model))
-    pos = jnp.broadcast_to(jnp.arange(64), (2, 64))
-    ref_out = common.attention(p, x, cfg, pos)
-    common.use_flash_kernel(True, interpret=True)
-    try:
-        flash_out = common.attention(p, x, cfg, pos)
-    finally:
-        common.use_flash_kernel(False)
-    np.testing.assert_allclose(np.asarray(flash_out), np.asarray(ref_out),
-                               rtol=2e-3, atol=2e-3)

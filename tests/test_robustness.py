@@ -442,32 +442,3 @@ def test_topic_refresh_rolls_back_on_unhealthy_update(topic_model):
     assert len(srv._refresh_buf) == 1   # re-buffered for the next attempt
     assert srv.refresh() == 1           # and the retry lands
     assert int(topic_model.health_) < 0
-
-
-def test_serving_engine_validation_rejects_without_model():
-    from repro.serving.engine import Request, ServingEngine
-
-    class Shell(ServingEngine):
-        """Validation only — no params, no cache, no decode."""
-
-        def __init__(self):
-            self.cfg = type("Cfg", (), {"vocab": 64})()
-            self.max_batch = 4
-            self.max_seq = 32
-            self.slots = [None] * 4
-            self.queue = []
-
-    eng = Shell()
-    bad = [Request(rid=1, prompt=[], max_new=3),
-           Request(rid=2, prompt=[1, 999], max_new=3),
-           Request(rid=3, prompt=[1, 2], max_new=0),
-           Request(rid=4, prompt=[1, 2], max_new=64)]
-    for r in bad:
-        r.out = []
-        eng.queue.append(r)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        eng._admit()
-    assert all(r.error is not None for r in bad)
-    assert all(s is None for s in eng.slots)
-    assert len(w) == 4

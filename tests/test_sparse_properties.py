@@ -14,7 +14,8 @@ Invariants pinned here:
   block-columns within every row-block (the layout the Pallas kernels
   stream by);
 * **oracle agreement** — every format and both ``BSROperand``
-  orientations reconstruct the dense matrix exactly;
+  orientations reconstruct the dense matrix exactly, and the ``SpCSR``
+  products ``A @ U`` and ``A.T @ W`` match the dense ones;
 * **carve equivalence** — ``ColumnSlicer`` (the reusable column-sorted
   index the streaming sources carve through) produces bit-identical
   chunks to the one-shot ``column_block`` scan, and a
@@ -24,6 +25,7 @@ Invariants pinned here:
 import shutil
 import tempfile
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -35,7 +37,8 @@ from repro.kernels.bsr import (
     bsr_transpose,
 )
 from repro.sparse.csr import (
-    ColumnSlicer, SpCSR, column_block, from_coo, from_dense, to_dense,
+    ColumnSlicer, SpCSR, column_block, from_coo, from_dense, spmm, spmm_t,
+    to_dense,
 )
 
 
@@ -304,3 +307,35 @@ def test_transpose_agrees_with_dense_oracle():
     a = bsr_from_dense(a_dense, bm=4, bk=4)
     at = bsr_transpose(a)
     np.testing.assert_array_equal(dense_of_bsr(at, (18, 12)), a_dense.T)
+
+
+# ---------------------------------------------------------------------------
+# SpCSR roundtrip and products vs the dense oracle
+# ---------------------------------------------------------------------------
+
+@settings(deadline=None, max_examples=20)
+@given(n=st.integers(2, 40), m=st.integers(2, 40),
+       density=st.floats(0.02, 0.5), seed=st.integers(0, 999))
+def test_csr_roundtrip(n, m, density, seed):
+    rng = np.random.default_rng(seed)
+    a = rng.random((n, m)).astype(np.float32)
+    a[rng.random((n, m)) > density] = 0
+    sp = from_dense(a)
+    np.testing.assert_allclose(np.asarray(to_dense(sp)), a)
+    assert int(sp.nnz()) == int((a != 0).sum())
+
+
+@settings(deadline=None, max_examples=15)
+@given(n=st.integers(2, 30), m=st.integers(2, 30), k=st.integers(1, 6),
+       seed=st.integers(0, 999))
+def test_csr_matmuls_match_dense(n, m, k, seed):
+    rng = np.random.default_rng(seed)
+    a = rng.random((n, m)).astype(np.float32)
+    a[rng.random((n, m)) > 0.3] = 0
+    u = rng.standard_normal((m, k)).astype(np.float32)
+    w = rng.standard_normal((n, k)).astype(np.float32)
+    sp = from_dense(a)
+    np.testing.assert_allclose(np.asarray(spmm(sp, jnp.asarray(u))), a @ u,
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(spmm_t(sp, jnp.asarray(w))), a.T @ w,
+                               rtol=1e-4, atol=1e-4)
